@@ -51,8 +51,8 @@ def select_topk(acc: jax.Array, k: int, *, axis: int,
 
 def _kernel(x_ref, vals_ref, idx_ref, *, k: int):
     def emit(i, m, am):
-        pl.store(vals_ref, (pl.ds(0, 1), pl.ds(i, 1)), m[:, None])
-        pl.store(idx_ref, (pl.ds(0, 1), pl.ds(i, 1)), am[:, None])
+        vals_ref[pl.ds(0, 1), pl.ds(i, 1)] = m[:, None]
+        idx_ref[pl.ds(0, 1), pl.ds(i, 1)] = am[:, None]
 
     select_topk(x_ref[...], k, axis=1, emit=emit)
 

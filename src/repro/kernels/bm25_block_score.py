@@ -131,10 +131,8 @@ def _fused_kernel(tok_ref, loc_ref, sc_ref, uniq_ref, w_ref,
 
         def emit(i, m, am):                                      # m, am: [B]
             b = m.shape[0]
-            pl.store(vals_ref, (pl.ds(0, 1), pl.ds(i, 1), pl.ds(0, b)),
-                     m[None, None, :])
-            pl.store(idx_ref, (pl.ds(0, 1), pl.ds(i, 1), pl.ds(0, b)),
-                     am[None, None, :])
+            vals_ref[pl.ds(0, 1), pl.ds(i, 1), pl.ds(0, b)] = m[None, None, :]
+            idx_ref[pl.ds(0, 1), pl.ds(i, 1), pl.ds(0, b)] = am[None, None, :]
 
         select_topk(acc, k, axis=0, emit=emit)
 

@@ -89,8 +89,8 @@ def _fold_winners(ext_vals, ids_of_row, prev_ids, mv_ref, mi_ref, *,
         old = jnp.sum(jnp.where(oh, prev_ids, 0), axis=0)
         gid = jnp.where(am < n_rows, ids_of_row(am), old)
         gid = jnp.where(m > neg / 2, gid, -1)
-        pl.store(mv_ref, (pl.ds(r, 1), pl.ds(0, b)), m[None, :])
-        pl.store(mi_ref, (pl.ds(r, 1), pl.ds(0, b)), gid[None, :])
+        mv_ref[pl.ds(r, 1), pl.ds(0, b)] = m[None, :]
+        mi_ref[pl.ds(r, 1), pl.ds(0, b)] = gid[None, :]
 
     select_topk(ext_vals, k, axis=0, emit=emit)
 
@@ -120,10 +120,8 @@ def _gather_kernel(tok_ref, loc_ref, sc_ref, uniq_ref, w_ref, cand_ref,
         def emit(i, m, am):                                  # m, am: [B]
             b = m.shape[0]
             gid = jnp.take(cand, am)                         # slot -> doc id
-            pl.store(vals_ref, (pl.ds(0, 1), pl.ds(i, 1), pl.ds(0, b)),
-                     m[None, None, :])
-            pl.store(gid_ref, (pl.ds(0, 1), pl.ds(i, 1), pl.ds(0, b)),
-                     gid[None, None, :])
+            vals_ref[pl.ds(0, 1), pl.ds(i, 1), pl.ds(0, b)] = m[None, None, :]
+            gid_ref[pl.ds(0, 1), pl.ds(i, 1), pl.ds(0, b)] = gid[None, None, :]
 
         select_topk(acc, k, axis=0, emit=emit)
 
@@ -263,7 +261,7 @@ def _resident_scatter(acc_ref, w_ref, doc, sc, valid, uidx, blk, *,
     """
     ok = (jax.lax.broadcasted_iota(jnp.int32, (frag, 1), 0)
           < valid)                                       # [frag, 1]
-    w_row = pl.load(w_ref, (pl.ds(uidx, 1), slice(None)))  # [1, B]
+    w_row = w_ref[pl.ds(uidx, 1), :]                     # [1, B]
     contrib = jnp.where(ok, sc[:, None], 0.0) * w_row    # [frag, B]
     # over-read tail postings (ok == False) may carry arbitrary doc
     # ids, but their contrib rows are zero — a spurious one-hot match
@@ -488,7 +486,7 @@ def _resident_kernel_pruned(desc_ref, w_ref, bnd_ref, doc_hbm, sc_hbm,
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     # live iff ANY query's threshold is still reachable by this block
-    kth = pl.load(vals_ref, (pl.ds(k - 1, 1), slice(None)))[0, :]   # [B]
+    kth = vals_ref[pl.ds(k - 1, 1), :][0, :]                        # [B]
     live = jnp.any(bnd_ref[0, :] >= kth)
 
     @pl.when((valid > 0) & live)
