@@ -6,13 +6,22 @@ dimension") re-architected for the TPU memory hierarchy (DESIGN.md §3):
 * postings live in the static block-bucketed layout (block_csr.py) so every
   tile is a dense VMEM-resident rectangle;
 * the per-posting "is this token in the query batch, at what weight?" lookup
-  is a vectorized binary-search (comparison-count against the sorted
-  unique-token table, O(P·U) VPU compares) followed by a row gather of the
-  ``[U, B]`` weight table — NOT a one-hot matmul over U, which would cost
-  P·U·B MACs and dominate the useful work at realistic U;
+  is a ``[P, U]`` equality one-hot against the unique-token table (O(P·U)
+  VPU compares) times the ``[U, B]`` weight table on the MXU — Mosaic has
+  no in-kernel row gather, and at most one entry per one-hot row is set;
 * the scatter ``acc[local_doc] += score·w`` is a second one-hot matmul
   (``one_hot(local_doc)ᵀ @ contrib``) — the classic TPU answer to random
   scatter, with the one-hot built in-register from ``broadcasted_iota``.
+
+Both one-hot matmuls run at ``Precision.HIGHEST`` (:func:`onehot_dot`): a
+one-hot entry is exactly 1.0, so at full f32 precision every product is
+exact and the kernel reproduces the oracle's per-posting contributions —
+the MXU's default bf16 passes would round each weight to 8 mantissa bits.
+
+Posting arrays enter as ``[n, 1, P]`` rows (2-D ``[n, P]`` inputs are
+reshaped): a ``(1, tile_p)`` block of a 2-D array is not a legal TPU tile,
+while the squeezed leading dimension of the 3-D form is. The resident
+layout is stored 3-D already, so the reshape is free on the hot path.
 
 Grid: ``(n_blocks, nnz_pad // tile_p)``. The inner (posting-tile) dimension
 revisits the same output block, accumulating; program 0 zero-initializes.
@@ -59,30 +68,41 @@ from jax.experimental.pallas import tpu as pltpu
 from .blockwise_topk import select_topk
 
 
+def onehot_dot(a: jax.Array, b: jax.Array) -> jax.Array:
+    """``a @ b`` at full f32 precision (exact when ``a`` is a 0/1 one-hot)."""
+    return jax.lax.dot(a, b, precision=jax.lax.Precision.HIGHEST,
+                       preferred_element_type=jnp.float32)
+
+
+def as_posting_rows(*arrays: jax.Array) -> tuple[jax.Array, ...]:
+    """``[n, P]`` -> ``[n, 1, P]`` (3-D inputs pass through unchanged)."""
+    return tuple(a if a.ndim == 3 else a[:, None, :] for a in arrays)
+
+
+def posting_row_spec(tile_p: int) -> pl.BlockSpec:
+    """One ``(1, tile_p)`` posting row of an ``[n, 1, P]`` array per step."""
+    return pl.BlockSpec((None, 1, tile_p), lambda i, j: (i, 0, j))
+
+
 def _score_tile(tok_ref, loc_ref, sc_ref, uniq_ref, w_ref, *,
                 block_size: int) -> jax.Array:
     """One posting tile's ``[block_size, B]`` score contribution."""
     tok = tok_ref[0, :]                                   # [PT] int32
     loc = loc_ref[0, :]                                   # [PT] int32
     sc = sc_ref[0, :]                                     # [PT] f32
-    uniq = uniq_ref[...]                                  # [U]  int32
+    uniq = uniq_ref[...]                                  # [1, U] int32
     weights = w_ref[...]                                  # [U, B] f32
 
-    # membership lookup: idx[p] = #\{u : uniq[u] <= tok[p]\} - 1 (uniq sorted);
-    # a [PT, U] comparison-count on the VPU, then a row gather of weights.
-    # Padding postings (tok = -1) count 0 -> idx -1 -> clamped + masked out;
-    # padding table slots are INT32_MAX and never match.
-    le = (uniq[None, :] <= tok[:, None]).astype(jnp.int32)       # [PT, U]
-    idx = jnp.sum(le, axis=1) - 1                                # [PT]
-    safe = jnp.maximum(idx, 0)
-    w_rows = jnp.take(weights, safe, axis=0)                     # [PT, B]
-    hit = (jnp.take(uniq, safe) == tok)[:, None]                 # exact match
-    contrib = jnp.where(hit, w_rows, 0.0) * sc[:, None]          # [PT, B]
+    # membership one-hot: uniq holds distinct tokens padded with INT32_MAX
+    # and posting pads carry tok = -1, so each row has at most one hit and
+    # the matmul returns that token's weight row (or zeros) exactly
+    member = (uniq == tok[:, None]).astype(weights.dtype)       # [PT, U]
+    contrib = onehot_dot(member, weights) * sc[:, None]         # [PT, B]
 
     # scatter -> one-hot matmul: oneh[d, p] = (loc[p] == d)
     d_iota = jax.lax.broadcasted_iota(jnp.int32, (block_size, loc.shape[0]), 0)
     oneh = (d_iota == loc[None, :]).astype(weights.dtype)        # [BS, PT]
-    return oneh @ contrib                                        # [BS, B] MXU
+    return onehot_dot(oneh, contrib)                             # [BS, B]
 
 
 def _kernel(tok_ref, loc_ref, sc_ref, uniq_ref, w_ref, out_ref, *,
@@ -146,12 +166,14 @@ def bm25_block_score(token_ids: jax.Array, local_doc: jax.Array,
                      weights: jax.Array, *, block_size: int,
                      tile_p: int = 512, interpret: bool | None = None
                      ) -> jax.Array:
-    """[nb, P] blocked postings x [U, B] query table -> [nb, block_size, B].
+    """[nb, (1,) P] blocked postings x [U, B] query table -> [nb, block_size, B].
 
     Dense scores for oracle tests and full-score consumers; the retrieval
     path uses :func:`bm25_block_score_topk` instead.
     """
-    nb, p = token_ids.shape
+    token_ids, local_doc, scores = as_posting_rows(token_ids, local_doc,
+                                                   scores)
+    nb, _, p = token_ids.shape
     u, b = weights.shape
     assert p % tile_p == 0, (p, tile_p)
     if interpret is None:
@@ -162,17 +184,17 @@ def bm25_block_score(token_ids: jax.Array, local_doc: jax.Array,
         functools.partial(_kernel, block_size=block_size),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, tile_p), lambda i, j: (i, j)),       # token_ids
-            pl.BlockSpec((1, tile_p), lambda i, j: (i, j)),       # local_doc
-            pl.BlockSpec((1, tile_p), lambda i, j: (i, j)),       # scores
-            pl.BlockSpec((u,), lambda i, j: (0,)),                # uniq table
+            posting_row_spec(tile_p),                             # token_ids
+            posting_row_spec(tile_p),                             # local_doc
+            posting_row_spec(tile_p),                             # scores
+            pl.BlockSpec((1, u), lambda i, j: (0, 0)),            # uniq table
             pl.BlockSpec((u, b), lambda i, j: (0, 0)),            # weights
         ],
         out_specs=pl.BlockSpec((1, block_size, b), lambda i, j: (i, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((nb, block_size, b), weights.dtype),
         interpret=interpret,
         name="bm25_block_score",
-    )(token_ids, local_doc, scores, uniq_tokens, weights)
+    )(token_ids, local_doc, scores, uniq_tokens.reshape(1, u), weights)
 
 
 @functools.partial(
@@ -193,7 +215,9 @@ def bm25_block_score_topk(token_ids: jax.Array, local_doc: jax.Array,
     selection, so they can only surface when a block holds fewer than ``k``
     real documents. Ids are block-local; the merge adds ``block·block_size``.
     """
-    nb, p = token_ids.shape
+    token_ids, local_doc, scores = as_posting_rows(token_ids, local_doc,
+                                                   scores)
+    nb, _, p = token_ids.shape
     u, b = weights.shape
     assert p % tile_p == 0, (p, tile_p)
     assert k <= block_size, (k, block_size)
@@ -206,10 +230,10 @@ def bm25_block_score_topk(token_ids: jax.Array, local_doc: jax.Array,
                           n_docs=n_docs),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, tile_p), lambda i, j: (i, j)),       # token_ids
-            pl.BlockSpec((1, tile_p), lambda i, j: (i, j)),       # local_doc
-            pl.BlockSpec((1, tile_p), lambda i, j: (i, j)),       # scores
-            pl.BlockSpec((u,), lambda i, j: (0,)),                # uniq table
+            posting_row_spec(tile_p),                             # token_ids
+            posting_row_spec(tile_p),                             # local_doc
+            posting_row_spec(tile_p),                             # scores
+            pl.BlockSpec((1, u), lambda i, j: (0, 0)),            # uniq table
             pl.BlockSpec((u, b), lambda i, j: (0, 0)),            # weights
         ],
         out_specs=(
@@ -223,4 +247,4 @@ def bm25_block_score_topk(token_ids: jax.Array, local_doc: jax.Array,
         scratch_shapes=[pltpu.VMEM((block_size, b), weights.dtype)],
         interpret=interpret,
         name="bm25_block_score_topk",
-    )(token_ids, local_doc, scores, uniq_tokens, weights)
+    )(token_ids, local_doc, scores, uniq_tokens.reshape(1, u), weights)

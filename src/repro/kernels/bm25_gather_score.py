@@ -54,6 +54,7 @@ Three gathered entry points:
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -61,11 +62,12 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from .blockwise_topk import select_topk
-from .bm25_block_score import _score_tile
+from .bm25_block_score import (_score_tile, as_posting_rows, onehot_dot,
+                               posting_row_spec)
 
-# jax >= 0.5 renamed TPUMemorySpace -> MemorySpace
-_ANY_SPACE = getattr(pltpu, "MemorySpace",
-                     getattr(pltpu, "TPUMemorySpace", None)).ANY
+# HBM tiles the [1, nnz_pad] resident CSC arrays 128 lanes wide, and a DMA
+# may only start on a tile boundary
+_LANE = 128
 
 
 def _fold_winners(ext_vals, ids_of_row, prev_ids, mv_ref, mi_ref, *,
@@ -95,6 +97,14 @@ def _fold_winners(ext_vals, ids_of_row, prev_ids, mv_ref, mi_ref, *,
     select_topk(ext_vals, k, axis=0, emit=emit)
 
 
+def _slot_ids(cand, am):
+    """``cand[am]`` for a ``[rows, 1]`` id column and ``[B]`` row picks —
+    a one-hot select-and-sum (Mosaic lowers no dynamic vector gather)."""
+    oh = (jax.lax.broadcasted_iota(jnp.int32, (cand.shape[0], am.shape[0]), 0)
+          == am[None, :])
+    return jnp.sum(jnp.where(oh, cand, 0), axis=0)
+
+
 def _gather_kernel(tok_ref, loc_ref, sc_ref, uniq_ref, w_ref, cand_ref,
                    vals_ref, gid_ref, acc_ref, *, acc_block: int, k: int):
     """One (chunk, posting-tile) grid step of the gathered fused path."""
@@ -110,16 +120,15 @@ def _gather_kernel(tok_ref, loc_ref, sc_ref, uniq_ref, w_ref, cand_ref,
     @pl.when(pj == pl.num_programs(1) - 1)
     def _reduce():
         acc = acc_ref[...]                                   # [acc_block, B]
-        cand = cand_ref[0, :]                                # [acc_block]
+        cand = cand_ref[...]                                 # [acc_block, 1]
         # padding slots (no candidate doc) must not outrank real negative
         # scores — same contract as the full-scan kernel's tail-doc mask,
         # but driven by the candidate table instead of a static n_docs.
-        acc = jnp.where((cand >= 0)[:, None], acc,
-                        jnp.finfo(acc.dtype).min)
+        acc = jnp.where(cand >= 0, acc, jnp.finfo(acc.dtype).min)
 
         def emit(i, m, am):                                  # m, am: [B]
             b = m.shape[0]
-            gid = jnp.take(cand, am)                         # slot -> doc id
+            gid = _slot_ids(cand, am)                        # slot -> doc id
             vals_ref[pl.ds(0, 1), pl.ds(i, 1), pl.ds(0, b)] = m[None, None, :]
             gid_ref[pl.ds(0, 1), pl.ds(i, 1), pl.ds(0, b)] = gid[None, None, :]
 
@@ -157,13 +166,12 @@ def _gather_kernel_shard(tok_ref, loc_ref, sc_ref, uniq_ref, w_ref, cand_ref,
     @pl.when(pj == pl.num_programs(1) - 1)
     def _reduce():
         acc = acc_ref[...]                                   # [acc_block, B]
-        cand = cand_ref[0, :]                                # [acc_block]
-        acc = jnp.where((cand >= 0)[:, None], acc, neg)
+        cand = cand_ref[...]                                 # [acc_block, 1]
+        acc = jnp.where(cand >= 0, acc, neg)
         prev_v, prev_i = vals_ref[...], gid_ref[...]
         ext = jnp.concatenate([acc, prev_v], axis=0)
-        _fold_winners(
-            ext, lambda am: jnp.take(cand, jnp.minimum(am, acc_block - 1)),
-            prev_i, mv_ref, mi_ref, n_rows=acc_block, k=k)
+        _fold_winners(ext, lambda am: _slot_ids(cand, am), prev_i, mv_ref,
+                      mi_ref, n_rows=acc_block, k=k)
         vals_ref[...] = mv_ref[...]
         gid_ref[...] = mi_ref[...]
 
@@ -193,7 +201,9 @@ def bm25_gather_score_topk(token_ids: jax.Array, slot_ids: jax.Array,
     output is ``[k, B]``, cutting HBM winner traffic and the host merge by
     ``n_chunks``×.
     """
-    nc, p = token_ids.shape
+    token_ids, slot_ids, scores = as_posting_rows(token_ids, slot_ids,
+                                                  scores)
+    nc, _, p = token_ids.shape
     u, b = weights.shape
     assert p % tile_p == 0, (p, tile_p)
     assert k <= acc_block, (k, acc_block)
@@ -204,13 +214,16 @@ def bm25_gather_score_topk(token_ids: jax.Array, slot_ids: jax.Array,
 
     grid = (nc, p // tile_p)
     in_specs = [
-        pl.BlockSpec((1, tile_p), lambda i, j: (i, j)),      # token_ids
-        pl.BlockSpec((1, tile_p), lambda i, j: (i, j)),      # slot_ids
-        pl.BlockSpec((1, tile_p), lambda i, j: (i, j)),      # scores
-        pl.BlockSpec((u,), lambda i, j: (0,)),               # uniq table
+        posting_row_spec(tile_p),                            # token_ids
+        posting_row_spec(tile_p),                            # slot_ids
+        posting_row_spec(tile_p),                            # scores
+        pl.BlockSpec((1, u), lambda i, j: (0, 0)),           # uniq table
         pl.BlockSpec((u, b), lambda i, j: (0, 0)),           # weights
-        pl.BlockSpec((1, acc_block), lambda i, j: (i, 0)),   # candidates
+        pl.BlockSpec((None, acc_block, 1),
+                     lambda i, j: (i, 0, 0)),                # candidate col
     ]
+    operands = (token_ids, slot_ids, scores, uniq_tokens.reshape(1, u),
+                weights, candidates.reshape(nc, acc_block, 1))
     if two_level:
         return pl.pallas_call(
             functools.partial(_gather_kernel_shard, acc_block=acc_block,
@@ -232,7 +245,7 @@ def bm25_gather_score_topk(token_ids: jax.Array, slot_ids: jax.Array,
             ],
             interpret=interpret,
             name="bm25_gather_score_topk_two_level",
-        )(token_ids, slot_ids, scores, uniq_tokens, weights, candidates)
+        )(*operands)
     return pl.pallas_call(
         functools.partial(_gather_kernel, acc_block=acc_block, k=k),
         grid=grid,
@@ -248,28 +261,51 @@ def bm25_gather_score_topk(token_ids: jax.Array, slot_ids: jax.Array,
         scratch_shapes=[pltpu.VMEM((acc_block, b), weights.dtype)],
         interpret=interpret,
         name="bm25_gather_score_topk",
-    )(token_ids, slot_ids, scores, uniq_tokens, weights, candidates)
+    )(*operands)
 
 
-def _resident_scatter(acc_ref, w_ref, doc, sc, valid, uidx, blk, *,
-                      block_size: int, frag: int):
+def _dma_window(frag: int) -> tuple[int, int]:
+    """``(align, width)`` of the posting window one fragment DMA copies.
+
+    A fragment starts at an arbitrary posting offset, so the kernel copies
+    the ``width = frag + align`` postings from the ``align``-aligned
+    position at or below it and masks the lanes outside the fragment.
+    ``align`` is the lane tile (128) whenever ``frag`` is a multiple of it
+    — the resident arrays' ``frag`` tail padding then covers the extra
+    lanes — and ``gcd(frag, 128)`` for the small fragments of CPU tests.
+    """
+    align = math.gcd(frag, _LANE)
+    return align, frag + align
+
+
+def _window_start(start, align: int):
+    """Fragment start -> (aligned DMA start, fragment lane offset)."""
+    base = pl.multiple_of((start // align) * align, align)
+    return base, start - base
+
+
+def _resident_scatter(acc_ref, w_ref, doc, sc, off, valid, uidx, blk, *,
+                      block_size: int, width: int):
     """Scatter one fragment's postings into the block accumulator.
 
-    The ONE scoring definition shared by the single- and double-buffered
-    resident kernels — identical operations in identical order, so the
-    two paths are bit-identical (the double-buffer test asserts it).
+    ``doc``/``sc`` are the fragment's DMA window; its postings are lanes
+    ``[off, off + valid)``. The ONE scoring definition shared by the
+    single-buffered, double-buffered and pruned resident kernels —
+    identical operations in identical order, so the paths are
+    bit-identical (the double-buffer test asserts it).
     """
-    ok = (jax.lax.broadcasted_iota(jnp.int32, (frag, 1), 0)
-          < valid)                                       # [frag, 1]
+    lane = jax.lax.broadcasted_iota(jnp.int32, (width, 1), 0)
+    ok = (lane >= off) & (lane < off + valid)            # [W, 1]
     w_row = w_ref[pl.ds(uidx, 1), :]                     # [1, B]
-    contrib = jnp.where(ok, sc[:, None], 0.0) * w_row    # [frag, B]
-    # over-read tail postings (ok == False) may carry arbitrary doc
-    # ids, but their contrib rows are zero — a spurious one-hot match
-    # adds exactly 0.
+    contrib = jnp.where(ok, sc[:, None], 0.0) * w_row    # [W, B]
+    # lanes outside the fragment may carry arbitrary doc ids, but their
+    # contrib rows are zero — a spurious one-hot match adds exactly 0.
+    # One token's run holds each doc at most once, so every accumulator
+    # entry receives at most one nonzero product per fragment.
     loc = doc - blk * block_size
-    d_iota = jax.lax.broadcasted_iota(jnp.int32, (block_size, frag), 0)
+    d_iota = jax.lax.broadcasted_iota(jnp.int32, (block_size, width), 0)
     oneh = (d_iota == loc[None, :]).astype(contrib.dtype)
-    acc_ref[...] += oneh @ contrib                       # [BS, B] MXU
+    acc_ref[...] += onehot_dot(oneh, contrib)            # [BS, B] MXU
 
 
 def _resident_fold(acc_ref, vals_ref, gid_ref, mv_ref, mi_ref, blk, *,
@@ -308,7 +344,8 @@ def _resident_kernel(desc_ref, w_ref, doc_hbm, sc_hbm, vals_ref, gid_ref,
     plays for the two-level reduce.
     """
     i = pl.program_id(0)
-    start = desc_ref[0, i]
+    align, width = _dma_window(frag)
+    base, off = _window_start(desc_ref[0, i], align)
     valid = desc_ref[1, i]
     uidx = desc_ref[2, i]
     blk = desc_ref[3, i]
@@ -328,15 +365,16 @@ def _resident_kernel(desc_ref, w_ref, doc_hbm, sc_hbm, vals_ref, gid_ref,
     @pl.when(valid > 0)
     def _score():
         cp_d = pltpu.make_async_copy(
-            doc_hbm.at[pl.ds(0, 1), pl.ds(start, frag)], dbuf, dsem)
+            doc_hbm.at[pl.ds(0, 1), pl.ds(base, width)], dbuf, dsem)
         cp_s = pltpu.make_async_copy(
-            sc_hbm.at[pl.ds(0, 1), pl.ds(start, frag)], sbuf, ssem)
+            sc_hbm.at[pl.ds(0, 1), pl.ds(base, width)], sbuf, ssem)
         cp_d.start()
         cp_s.start()
         cp_d.wait()
         cp_s.wait()
-        _resident_scatter(acc_ref, w_ref, dbuf[0, :], sbuf[0, :], valid,
-                          uidx, blk, block_size=block_size, frag=frag)
+        _resident_scatter(acc_ref, w_ref, dbuf[0, :], sbuf[0, :], off,
+                          valid, uidx, blk, block_size=block_size,
+                          width=width)
 
     @pl.when(last == 1)
     def _reduce():
@@ -367,7 +405,8 @@ def _resident_kernel_db(desc_ref, w_ref, doc_hbm, sc_hbm, vals_ref, gid_ref,
     """
     i = pl.program_id(0)
     nf = pl.num_programs(0)
-    start = desc_ref[0, i]
+    align, width = _dma_window(frag)
+    base, off = _window_start(desc_ref[0, i], align)
     valid = desc_ref[1, i]
     uidx = desc_ref[2, i]
     blk = desc_ref[3, i]
@@ -378,15 +417,15 @@ def _resident_kernel_db(desc_ref, w_ref, doc_hbm, sc_hbm, vals_ref, gid_ref,
 
     def copies(s, dbuf, sbuf, dsem, ssem):
         return (pltpu.make_async_copy(
-                    doc_hbm.at[pl.ds(0, 1), pl.ds(s, frag)], dbuf, dsem),
+                    doc_hbm.at[pl.ds(0, 1), pl.ds(s, width)], dbuf, dsem),
                 pltpu.make_async_copy(
-                    sc_hbm.at[pl.ds(0, 1), pl.ds(s, frag)], sbuf, ssem))
+                    sc_hbm.at[pl.ds(0, 1), pl.ds(s, width)], sbuf, ssem))
 
     @pl.when(i == 0)
     def _init_out():
         vals_ref[...] = jnp.full_like(vals_ref, neg)
         gid_ref[...] = jnp.full_like(gid_ref, -1)
-        for cp in copies(start, dbuf0, sbuf0, dsem0, ssem0):
+        for cp in copies(base, dbuf0, sbuf0, dsem0, ssem0):
             cp.start()                            # warm-up: fragment 0
 
     @pl.when(first == 1)
@@ -396,41 +435,44 @@ def _resident_kernel_db(desc_ref, w_ref, doc_hbm, sc_hbm, vals_ref, gid_ref,
     # prefetch fragment i+1 into the slot this step does NOT consume
     @pl.when(i + 1 < nf)
     def _prefetch():
-        nstart = desc_ref[0, i + 1]
+        nbase, _ = _window_start(desc_ref[0, i + 1], align)
 
         @pl.when(even)
         def _into_slot1():
-            for cp in copies(nstart, dbuf1, sbuf1, dsem1, ssem1):
+            for cp in copies(nbase, dbuf1, sbuf1, dsem1, ssem1):
                 cp.start()
 
         @pl.when(jnp.logical_not(even))
         def _into_slot0():
-            for cp in copies(nstart, dbuf0, sbuf0, dsem0, ssem0):
+            for cp in copies(nbase, dbuf0, sbuf0, dsem0, ssem0):
                 cp.start()
 
     # wait on THIS fragment's slot (unconditionally — semaphores must
     # balance even for padding fragments)
     @pl.when(even)
     def _wait_slot0():
-        for cp in copies(start, dbuf0, sbuf0, dsem0, ssem0):
+        for cp in copies(base, dbuf0, sbuf0, dsem0, ssem0):
             cp.wait()
 
     @pl.when(jnp.logical_not(even))
     def _wait_slot1():
-        for cp in copies(start, dbuf1, sbuf1, dsem1, ssem1):
+        for cp in copies(base, dbuf1, sbuf1, dsem1, ssem1):
             cp.wait()
 
     @pl.when(valid > 0)
     def _score():
-        doc = jnp.where(even, dbuf0[0, :], dbuf1[0, :])   # [frag] int32
-        sc = jnp.where(even, sbuf0[0, :], sbuf1[0, :])    # [frag] f32
-        _resident_scatter(acc_ref, w_ref, doc, sc, valid, uidx, blk,
-                          block_size=block_size, frag=frag)
+        doc = jnp.where(even, dbuf0[0, :], dbuf1[0, :])   # [W] int32
+        sc = jnp.where(even, sbuf0[0, :], sbuf1[0, :])    # [W] f32
+        _resident_scatter(acc_ref, w_ref, doc, sc, off, valid, uidx, blk,
+                          block_size=block_size, width=width)
 
     @pl.when(last == 1)
     def _reduce():
         _resident_fold(acc_ref, vals_ref, gid_ref, mv_ref, mi_ref, blk,
                        block_size=block_size, k=k, n_docs=n_docs)
+
+
+_BOUND_ROWS = 8      # sublane tile: bound rows reach the kernel 8 at a time
 
 
 def _resident_kernel_pruned(desc_ref, w_ref, bnd_ref, doc_hbm, sc_hbm,
@@ -465,9 +507,12 @@ def _resident_kernel_pruned(desc_ref, w_ref, bnd_ref, doc_hbm, sc_hbm,
 
     ``skip_ref`` counts skipped real fragments — the kernel-level half of
     the pruned regime's observability (``last_plan.frags_skipped``).
+    ``bnd_ref`` holds the ``_BOUND_ROWS``-row group containing fragment
+    ``i``'s bound row (a single-row block is not a legal TPU tile).
     """
     i = pl.program_id(0)
-    start = desc_ref[0, i]
+    align, width = _dma_window(frag)
+    base, off = _window_start(desc_ref[0, i], align)
     valid = desc_ref[1, i]
     uidx = desc_ref[2, i]
     blk = desc_ref[3, i]
@@ -486,21 +531,23 @@ def _resident_kernel_pruned(desc_ref, w_ref, bnd_ref, doc_hbm, sc_hbm,
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     # live iff ANY query's threshold is still reachable by this block
-    kth = vals_ref[pl.ds(k - 1, 1), :][0, :]                        # [B]
-    live = jnp.any(bnd_ref[0, :] >= kth)
+    kth = vals_ref[pl.ds(k - 1, 1), :]                              # [1, B]
+    bnd = bnd_ref[pl.ds(i % _BOUND_ROWS, 1), :]                     # [1, B]
+    live = jnp.any(bnd >= kth)
 
     @pl.when((valid > 0) & live)
     def _score():
         cp_d = pltpu.make_async_copy(
-            doc_hbm.at[pl.ds(0, 1), pl.ds(start, frag)], dbuf, dsem)
+            doc_hbm.at[pl.ds(0, 1), pl.ds(base, width)], dbuf, dsem)
         cp_s = pltpu.make_async_copy(
-            sc_hbm.at[pl.ds(0, 1), pl.ds(start, frag)], sbuf, ssem)
+            sc_hbm.at[pl.ds(0, 1), pl.ds(base, width)], sbuf, ssem)
         cp_d.start()
         cp_s.start()
         cp_d.wait()
         cp_s.wait()
-        _resident_scatter(acc_ref, w_ref, dbuf[0, :], sbuf[0, :], valid,
-                          uidx, blk, block_size=block_size, frag=frag)
+        _resident_scatter(acc_ref, w_ref, dbuf[0, :], sbuf[0, :], off,
+                          valid, uidx, blk, block_size=block_size,
+                          width=width)
 
     @pl.when((valid > 0) & jnp.logical_not(live))
     def _count_skip():
@@ -539,8 +586,10 @@ def bm25_resident_score_topk_pruned(desc: jax.Array, weights: jax.Array,
     """
     nf = desc.shape[1]
     u, b = weights.shape
+    _, width = _dma_window(frag)
     assert desc.shape[0] == 6, desc.shape
     assert bounds.shape == (nf, b), (bounds.shape, nf, b)
+    assert nf % _BOUND_ROWS == 0, nf
     assert k <= block_size, (k, block_size)
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
@@ -550,9 +599,10 @@ def bm25_resident_score_topk_pruned(desc: jax.Array, weights: jax.Array,
         grid=(nf,),
         in_specs=[
             pl.BlockSpec((u, b), lambda i, d: (0, 0)),       # weights VMEM
-            pl.BlockSpec((1, b), lambda i, d: (i, 0)),       # bound row
-            pl.BlockSpec(memory_space=_ANY_SPACE),           # doc ids / HBM
-            pl.BlockSpec(memory_space=_ANY_SPACE),           # scores / HBM
+            pl.BlockSpec((_BOUND_ROWS, b),
+                         lambda i, d: (i // _BOUND_ROWS, 0)),  # bound rows
+            pl.BlockSpec(memory_space=pl.ANY),               # doc ids / HBM
+            pl.BlockSpec(memory_space=pl.ANY),               # scores / HBM
         ],
         out_specs=(
             pl.BlockSpec((k, b), lambda i, d: (0, 0)),       # shard values
@@ -561,8 +611,8 @@ def bm25_resident_score_topk_pruned(desc: jax.Array, weights: jax.Array,
         ),
         scratch_shapes=(
             [pltpu.VMEM((block_size, b), weights.dtype),     # block acc
-             pltpu.VMEM((1, frag), jnp.int32),               # doc-id tile
-             pltpu.VMEM((1, frag), jnp.float32),             # score tile
+             pltpu.VMEM((1, width), jnp.int32),              # doc-id window
+             pltpu.VMEM((1, width), jnp.float32),            # score window
              pltpu.SemaphoreType.DMA,
              pltpu.SemaphoreType.DMA,
              pltpu.VMEM((k, b), weights.dtype),              # fold staging
@@ -614,23 +664,24 @@ def bm25_resident_score_topk(desc: jax.Array, weights: jax.Array,
     """
     nf = desc.shape[1]
     u, b = weights.shape
+    _, width = _dma_window(frag)
     assert desc.shape[0] == 6, desc.shape
     assert k <= block_size, (k, block_size)
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
 
     tile_scratch = [
-        pltpu.VMEM((1, frag), jnp.int32),                # doc-id tile
-        pltpu.VMEM((1, frag), jnp.float32),              # score tile
+        pltpu.VMEM((1, width), jnp.int32),               # doc-id window
+        pltpu.VMEM((1, width), jnp.float32),             # score window
         pltpu.SemaphoreType.DMA,
         pltpu.SemaphoreType.DMA,
     ]
     if double_buffer:
         tile_scratch = [
-            pltpu.VMEM((1, frag), jnp.int32),            # slot-0 doc tile
-            pltpu.VMEM((1, frag), jnp.float32),          # slot-0 score tile
-            pltpu.VMEM((1, frag), jnp.int32),            # slot-1 doc tile
-            pltpu.VMEM((1, frag), jnp.float32),          # slot-1 score tile
+            pltpu.VMEM((1, width), jnp.int32),           # slot-0 doc window
+            pltpu.VMEM((1, width), jnp.float32),         # slot-0 scores
+            pltpu.VMEM((1, width), jnp.int32),           # slot-1 doc window
+            pltpu.VMEM((1, width), jnp.float32),         # slot-1 scores
             pltpu.SemaphoreType.DMA,
             pltpu.SemaphoreType.DMA,
             pltpu.SemaphoreType.DMA,
@@ -641,8 +692,8 @@ def bm25_resident_score_topk(desc: jax.Array, weights: jax.Array,
         grid=(nf,),
         in_specs=[
             pl.BlockSpec((u, b), lambda i, d: (0, 0)),       # weights VMEM
-            pl.BlockSpec(memory_space=_ANY_SPACE),           # doc ids / HBM
-            pl.BlockSpec(memory_space=_ANY_SPACE),           # scores / HBM
+            pl.BlockSpec(memory_space=pl.ANY),               # doc ids / HBM
+            pl.BlockSpec(memory_space=pl.ANY),               # scores / HBM
         ],
         out_specs=(
             pl.BlockSpec((k, b), lambda i, d: (0, 0)),       # shard values

@@ -82,6 +82,18 @@ def put_posting_arrays(*arrays):
     return out[0] if len(out) == 1 else tuple(out)
 
 
+def put_blocked_postings(token_ids, local_doc, scores):
+    """Upload a blocked layout as ``[nb, 1, P]`` posting rows.
+
+    The blocked kernels read one ``(1, tile_p)`` row per grid step, a legal
+    TPU block only with the unit middle dimension; the 3-D array tiles
+    ``(1, 128)`` in HBM with no padding, so storing it in that form spares
+    every launch a relayout copy of the whole layout.
+    """
+    return put_posting_arrays(*(np.asarray(a)[:, None, :]
+                                for a in (token_ids, local_doc, scores)))
+
+
 def put_descriptor_array(arr):
     """Upload a run/fragment descriptor table (O(U) metadata, not postings)."""
     import jax.numpy as jnp
@@ -874,7 +886,7 @@ class DeviceIndex:
     csc_doc_ids: object = None   # [1, nnz_pad] int32 device (or None)
     csc_scores: object = None    # [1, nnz_pad] f32 device (or None)
     csc_indptr: object = None    # [V+1] int32 device (device plan builder)
-    blk_tok: object = None       # [nb, p_pad] int32 device (or None)
+    blk_tok: object = None       # [nb, 1, p_pad] int32 device (or None)
     blk_loc: object = None
     blk_sc: object = None
     bmax: object = None          # BlockMaxTable (pruned regime) or None
@@ -982,7 +994,7 @@ class DeviceIndex:
                     index.indptr.astype(np.int32))
         if with_blocked:
             if same_grid and old.blk_tok is not None \
-                    and old.tile_p == min(tile, old.blk_tok.shape[1]):
+                    and old.tile_p == min(tile, old.blk_tok.shape[-1]):
                 di.tile_p = old.tile_p
                 di.blk_tok, di.blk_loc, di.blk_sc = (old.blk_tok,
                                                      old.blk_loc, old.blk_sc)
@@ -991,7 +1003,7 @@ class DeviceIndex:
                 bp = block_postings_from_index(index, block_size=block_size,
                                                tile=tile)
                 di.tile_p = min(tile, bp.nnz_pad)
-                di.blk_tok, di.blk_loc, di.blk_sc = put_posting_arrays(
+                di.blk_tok, di.blk_loc, di.blk_sc = put_blocked_postings(
                     bp.token_ids, bp.local_doc, bp.scores)
         if with_bmax and with_csc:
             if same_grid and old.bmax is not None \

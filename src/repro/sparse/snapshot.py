@@ -123,6 +123,7 @@ from .block_csr import (
     _round_up,
     block_postings_from_index,
     build_block_max,
+    put_blocked_postings,
     put_descriptor_array,
     put_posting_arrays,
 )
@@ -408,8 +409,8 @@ def save_device_index(di: DeviceIndex, path: str, *, index=None,
     else:
         doc_pad, sc_pad = _padded_csc(index_c, di.frag)
     if di.blk_tok is not None:
-        blk = (np.asarray(di.blk_tok), np.asarray(di.blk_loc),
-               np.asarray(di.blk_sc))
+        blk = tuple(np.asarray(a)[:, 0, :]
+                    for a in (di.blk_tok, di.blk_loc, di.blk_sc))
     elif host_intact:
         bp = block_postings_from_index(index_l, block_size=di.block_size,
                                        tile=di.tile_p)
@@ -913,7 +914,7 @@ def load_device_index(path: str, *, mmap: bool = False,
         di.csc_indptr = put_descriptor_array(
             np.asarray(index.indptr).astype(np.int32))
         if ld.blk is not None:
-            di.blk_tok, di.blk_loc, di.blk_sc = put_posting_arrays(*ld.blk)
+            di.blk_tok, di.blk_loc, di.blk_sc = put_blocked_postings(*ld.blk)
             di.tile_p = min(int(dev["tile_p"]), int(ld.blk[0].shape[1]))
         if ld.bmax_rebuild:
             di.bmax = build_block_max(
