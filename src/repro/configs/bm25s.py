@@ -90,7 +90,7 @@ def _score_blocked_cell(*, doc_block: int = DOC_BLOCK,
     nnz_pad = int(-(-AVG_UNIQUE_TOKENS * doc_block // 512) * 512)
 
     def build(mesh):
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         from ..kernels.ref import bm25_block_score_ref
         from ..core.retrieval import blockwise_topk
         axes = tuple(mesh.shape.keys())

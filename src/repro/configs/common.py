@@ -53,12 +53,13 @@ def remesh_dp_tp(dp: int, tp: int) -> Callable:
     is folded into data.
     """
     def fn(mesh: Mesh):
+        from jax.sharding import AxisType
         from jax.sharding import Mesh as M
 
-        from ..launch.mesh import _axis_types
         devs = np.asarray(mesh.devices).reshape(-1)
         assert devs.size == dp * tp, (devs.size, dp, tp)
-        return M(devs.reshape(dp, tp), ("data", "model"), **_axis_types(2))
+        return M(devs.reshape(dp, tp), ("data", "model"),
+                 axis_types=(AxisType.Auto,) * 2)
     return fn
 
 

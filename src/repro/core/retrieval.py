@@ -20,7 +20,7 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from .scoring import DeviceIndex, score_query
@@ -589,7 +589,7 @@ def make_sharded_retrieve(mesh: Mesh, shard_axes: tuple[str, ...], *,
 
     @jax.jit
     def retrieve(idx_arrays, q_tokens, q_weights):
-        # check_rep: the gathered step's jnp.unique lowers to a scan whose
+        # check_vma: the gathered step's jnp.unique lowers to a scan whose
         # carry trips shard_map's replication checker on replicated query
         # operands (a checker false positive) — the computation itself is
         # shard-local either way.
@@ -597,7 +597,7 @@ def make_sharded_retrieve(mesh: Mesh, shard_axes: tuple[str, ...], *,
             local_score_topk, mesh=mesh,
             in_specs=(spec_idx, P(), P()),
             out_specs=(P(shard_axes), P(shard_axes), P(shard_axes)),
-            check_rep=not gathered,
+            check_vma=not gathered,
         )(idx_arrays, q_tokens, q_weights)
         # [n_shards, B, k] -> [B, n_shards*k] -> global top-k (the merge)
         b = q_tokens.shape[0]

@@ -23,10 +23,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax >= 0.5 renamed TPUMemorySpace -> MemorySpace
-_ANY_SPACE = getattr(pltpu, "MemorySpace",
-                     getattr(pltpu, "TPUMemorySpace", None)).ANY
-
 
 def _kernel(idx_ref, w_ref, table_ref, out_ref, row0, row1, sem0, sem1, *,
             fanout: int, tile_b: int):
@@ -99,7 +95,7 @@ def embedding_bag_kernel(table: jax.Array, indices: jax.Array,
         grid=(b // tile_b,),
         in_specs=[
             pl.BlockSpec((tile_b, f), lambda i, idx: (i, 0)),     # weights
-            pl.BlockSpec(memory_space=_ANY_SPACE),                # table/HBM
+            pl.BlockSpec(memory_space=pl.ANY),                # table/HBM
         ],
         out_specs=pl.BlockSpec((tile_b, d), lambda i, idx: (i, 0)),
         scratch_shapes=[

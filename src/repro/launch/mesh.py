@@ -14,20 +14,11 @@ from __future__ import annotations
 
 
 import jax
-from jax.sharding import Mesh
-
-try:                       # jax >= 0.5: meshes carry explicit axis types
-    from jax.sharding import AxisType
-
-    def _axis_types(n: int) -> dict:
-        return {"axis_types": (AxisType.Auto,) * n}
-except ImportError:        # older jax: every axis is implicitly "auto"
-    def _axis_types(n: int) -> dict:
-        return {}
+from jax.sharding import AxisType, Mesh
 
 
 def _make(shape, axes) -> Mesh:
-    return jax.make_mesh(shape, axes, **_axis_types(len(axes)))
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
@@ -50,7 +41,8 @@ def make_mesh_from(devices=None, *, max_model: int = 16) -> Mesh:
     data = n // model
     import numpy as np
     dev_array = np.asarray(devices[: data * model]).reshape(data, model)
-    return Mesh(dev_array, ("data", "model"), **_axis_types(2))
+    return Mesh(dev_array, ("data", "model"),
+                axis_types=(AxisType.Auto,) * 2)
 
 
 def make_test_mesh(n_devices: int | None = None) -> Mesh:
