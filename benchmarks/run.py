@@ -27,6 +27,9 @@ def main() -> None:
                          "BENCH_* artifacts")
     args = ap.parse_args()
 
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
+
     from . import fused, gathered, kernels_bench, planner, throughput, \
         tokenization, variants
 

@@ -33,11 +33,11 @@ class ScipyBM25:
 
     def __init__(self, index: BM25Index):
         self.index = index
-        df = np.diff(index.indptr)
-        tok = np.repeat(np.arange(index.n_vocab, dtype=np.int64), df)
-        # docs × tokens so that CSC stores each token's postings contiguously
+        # docs × tokens: the index IS this matrix's CSC form (each token's
+        # postings contiguous, doc ids ascending and unique within a
+        # token), so its arrays are adopted without a COO round trip
         self.matrix = sp.csc_matrix(
-            (index.scores, (index.doc_ids, tok)),
+            (index.scores, index.doc_ids, index.indptr),
             shape=(index.doc_lens.size, index.n_vocab),
         )
         self.nonoccurrence = index.nonoccurrence

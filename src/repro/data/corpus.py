@@ -83,14 +83,28 @@ class SyntheticCorpus:
 
 def zipf_corpus(n_docs: int, n_vocab: int, *, avg_len: int = 100,
                 seed: int = 0, alpha: float = 1.07) -> list[np.ndarray]:
-    """Id-level Zipf corpus for throughput benchmarks (no strings)."""
+    """Id-level Zipf corpus for throughput benchmarks (no strings).
+
+    Every token of the corpus comes from one inverse-CDF draw over the
+    Zipf table (done in fixed-size chunks to bound memory), then split at
+    the Poisson document lengths — the same stream, token for token, as
+    one ``rng.choice(n_vocab, size=len, p=p)`` per document, at a cost
+    independent of ``n_vocab`` per document. Documents are views into one
+    int32 array.
+    """
     rng = np.random.default_rng(seed)
     ranks = np.arange(1, n_vocab + 1, dtype=np.float64)
     p = ranks ** -alpha
     p /= p.sum()
+    cdf = p.cumsum()
+    cdf /= cdf[-1]
     lens = np.maximum(1, rng.poisson(avg_len, size=n_docs))
-    return [rng.choice(n_vocab, size=int(l), p=p).astype(np.int32)
-            for l in lens]
+    tokens = np.empty(int(lens.sum()), dtype=np.int32)
+    chunk = 1 << 24
+    for lo in range(0, tokens.size, chunk):
+        u = rng.random(min(chunk, tokens.size - lo))
+        tokens[lo:lo + u.size] = cdf.searchsorted(u, side="right")
+    return np.split(tokens, np.cumsum(lens)[:-1])
 
 
 def zipf_queries(n_queries: int, n_vocab: int, *, q_len: int = 5,
