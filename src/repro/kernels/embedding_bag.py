@@ -95,7 +95,7 @@ def embedding_bag_kernel(table: jax.Array, indices: jax.Array,
         grid=(b // tile_b,),
         in_specs=[
             pl.BlockSpec((tile_b, f), lambda i, idx: (i, 0)),     # weights
-            pl.BlockSpec(memory_space=pl.ANY),                # table/HBM
+            pl.BlockSpec(memory_space=pl.ANY),                    # table/HBM
         ],
         out_specs=pl.BlockSpec((tile_b, d), lambda i, idx: (i, 0)),
         scratch_shapes=[

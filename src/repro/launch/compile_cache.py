@@ -11,8 +11,8 @@ from __future__ import annotations
 import os
 from pathlib import Path
 
-# a fixed directory inside the checkout (git-ignored): the cache key
-# includes the path, so a temporary or per-process directory never hits
+# a fixed directory inside the checkout (git-ignored): a temporary or
+# per-process directory would be empty on every run and never hit
 CHECKOUT_CACHE_DIR = Path(__file__).resolve().parents[3] / ".jax_cache"
 
 

@@ -8,7 +8,6 @@ check of the script is exercised by tier-1, and a broken board fails it.
 """
 
 import importlib.util
-import json
 from pathlib import Path
 
 import numpy as np
