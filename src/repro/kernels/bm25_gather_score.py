@@ -35,9 +35,8 @@ Three gathered entry points:
   host merge from ``[nc·k, B]`` to ``[k, B]``.
 * ``bm25_resident_score_topk``   — the zero-copy path: posting arrays are
   HBM-resident (``sparse.block_csr.DeviceIndex``), the host ships only a
-  fragment-descriptor table (``fragment_plan``) which is scalar-prefetched
-  into SMEM (the ``PrefetchScalarGridSpec`` pattern proven in
-  ``kernels/embedding_bag.py``); each grid step DMAs one ≤``frag``-sized
+  fragment-descriptor table (``fragment_plan``) which streams through
+  SMEM in chunks; each grid step DMAs one ≤``frag``-sized
   posting run fragment straight out of HBM, scatters it into a per-doc-block
   VMEM accumulator, and block winners fold into the same running ``[k, B]``
   shard scoreboard. No membership search is needed at all — the descriptor
@@ -68,6 +67,30 @@ from .bm25_block_score import (_score_tile, as_posting_rows, onehot_dot,
 # HBM tiles the [1, nnz_pad] resident CSC arrays 128 lanes wide, and a DMA
 # may only start on a tile boundary
 _LANE = 128
+
+# The fragment table drives every grid step's DMAs, so the resident kernels
+# read it from SMEM. It streams through in chunks of this many fragments:
+# scalar-prefetched whole it overflows the v5e's 1 MiB of SMEM (rows pad
+# to 8) past 32k fragments, which one 32-query batch passes at 2^18 docs.
+_DESC_CHUNK = 1024
+
+
+def _desc_spec(nf: int, ahead: int = 0) -> pl.BlockSpec:
+    """The SMEM chunk of the ``[6, nf]`` fragment table that holds fragment
+    ``i + ahead`` at grid step ``i`` (the last chunk past the end)."""
+    chunk = min(nf, _DESC_CHUNK)
+    assert nf % chunk == 0, (nf, chunk)          # nf_pad is a power of two
+    last = nf // chunk - 1
+    return pl.BlockSpec(
+        (6, chunk), lambda i: (0, jnp.minimum((i + ahead) // chunk, last)),
+        memory_space=pltpu.SMEM)
+
+
+def _fragment(desc_ref, i):
+    """``(start, valid, uniq, block, first, last)`` of fragment ``i`` from
+    the SMEM chunk that holds it."""
+    c = i % desc_ref.shape[1]
+    return tuple(desc_ref[r, c] for r in range(6))
 
 
 def _fold_winners(ext_vals, ids_of_row, prev_ids, mv_ref, mi_ref, *,
@@ -328,8 +351,7 @@ def _resident_kernel(desc_ref, w_ref, doc_hbm, sc_hbm, vals_ref, gid_ref,
                      block_size: int, frag: int, k: int, n_docs: int):
     """One fragment of the device-resident gather→score→top-k path.
 
-    The grid walks the batch's fragment table (SMEM, scalar-prefetched;
-    see ``sparse.block_csr.FragmentPlan`` for the row layout). Each step
+    The grid walks the batch's fragment table (SMEM chunks; see ``sparse.block_csr.FragmentPlan`` for the row layout). Each step
     DMAs its ≤``frag`` postings (doc ids + eager scores) out of the
     HBM-resident CSC arrays at a descriptor-driven dynamic offset, scales
     by the owning token's ``[B]`` query-weight row (named by the
@@ -344,13 +366,9 @@ def _resident_kernel(desc_ref, w_ref, doc_hbm, sc_hbm, vals_ref, gid_ref,
     plays for the two-level reduce.
     """
     i = pl.program_id(0)
+    start, valid, uidx, blk, first, last = _fragment(desc_ref, i)
     align, width = _dma_window(frag)
-    base, off = _window_start(desc_ref[0, i], align)
-    valid = desc_ref[1, i]
-    uidx = desc_ref[2, i]
-    blk = desc_ref[3, i]
-    first = desc_ref[4, i]
-    last = desc_ref[5, i]
+    base, off = _window_start(start, align)
     neg = jnp.finfo(vals_ref.dtype).min
 
     @pl.when(i == 0)
@@ -382,10 +400,10 @@ def _resident_kernel(desc_ref, w_ref, doc_hbm, sc_hbm, vals_ref, gid_ref,
                        block_size=block_size, k=k, n_docs=n_docs)
 
 
-def _resident_kernel_db(desc_ref, w_ref, doc_hbm, sc_hbm, vals_ref, gid_ref,
-                        acc_ref, dbuf0, sbuf0, dbuf1, sbuf1, dsem0, ssem0,
-                        dsem1, ssem1, mv_ref, mi_ref, *, block_size: int,
-                        frag: int, k: int, n_docs: int):
+def _resident_kernel_db(desc_ref, nxt_ref, w_ref, doc_hbm, sc_hbm, vals_ref,
+                        gid_ref, acc_ref, dbuf0, sbuf0, dbuf1, sbuf1, dsem0,
+                        ssem0, dsem1, ssem1, mv_ref, mi_ref, *,
+                        block_size: int, frag: int, k: int, n_docs: int):
     """Double-buffered variant: fragment f+1's DMAs fly during f's scatter.
 
     Same math as :func:`_resident_kernel` (both call
@@ -401,17 +419,14 @@ def _resident_kernel_db(desc_ref, w_ref, doc_hbm, sc_hbm, vals_ref, gid_ref,
     there and the resident arrays over-allocate a full ``frag`` tail), so
     start/wait stay balanced with no cross-step control flow; padding
     still contributes nothing because the scatter is gated on
-    ``valid > 0``.
+    ``valid > 0``. ``nxt_ref`` is the fragment table's SMEM chunk that
+    holds fragment ``i + 1``.
     """
     i = pl.program_id(0)
     nf = pl.num_programs(0)
+    start, valid, uidx, blk, first, last = _fragment(desc_ref, i)
     align, width = _dma_window(frag)
-    base, off = _window_start(desc_ref[0, i], align)
-    valid = desc_ref[1, i]
-    uidx = desc_ref[2, i]
-    blk = desc_ref[3, i]
-    first = desc_ref[4, i]
-    last = desc_ref[5, i]
+    base, off = _window_start(start, align)
     even = i % 2 == 0
     neg = jnp.finfo(vals_ref.dtype).min
 
@@ -435,7 +450,8 @@ def _resident_kernel_db(desc_ref, w_ref, doc_hbm, sc_hbm, vals_ref, gid_ref,
     # prefetch fragment i+1 into the slot this step does NOT consume
     @pl.when(i + 1 < nf)
     def _prefetch():
-        nbase, _ = _window_start(desc_ref[0, i + 1], align)
+        nstart = _fragment(nxt_ref, i + 1)[0]
+        nbase, _ = _window_start(nstart, align)
 
         @pl.when(even)
         def _into_slot1():
@@ -511,13 +527,9 @@ def _resident_kernel_pruned(desc_ref, w_ref, bnd_ref, doc_hbm, sc_hbm,
     ``i``'s bound row (a single-row block is not a legal TPU tile).
     """
     i = pl.program_id(0)
+    start, valid, uidx, blk, first, last = _fragment(desc_ref, i)
     align, width = _dma_window(frag)
-    base, off = _window_start(desc_ref[0, i], align)
-    valid = desc_ref[1, i]
-    uidx = desc_ref[2, i]
-    blk = desc_ref[3, i]
-    first = desc_ref[4, i]
-    last = desc_ref[5, i]
+    base, off = _window_start(start, align)
     neg = jnp.finfo(vals_ref.dtype).min
 
     @pl.when(i == 0)
@@ -594,20 +606,22 @@ def bm25_resident_score_topk_pruned(desc: jax.Array, weights: jax.Array,
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
 
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,                    # desc table -> SMEM
+    return pl.pallas_call(
+        functools.partial(_resident_kernel_pruned, block_size=block_size,
+                          frag=frag, k=k, n_docs=n_docs),
         grid=(nf,),
         in_specs=[
-            pl.BlockSpec((u, b), lambda i, d: (0, 0)),       # weights VMEM
+            _desc_spec(nf),                                  # desc / SMEM
+            pl.BlockSpec((u, b), lambda i: (0, 0)),          # weights VMEM
             pl.BlockSpec((_BOUND_ROWS, b),
-                         lambda i, d: (i // _BOUND_ROWS, 0)),  # bound rows
+                         lambda i: (i // _BOUND_ROWS, 0)),   # bound rows
             pl.BlockSpec(memory_space=pl.ANY),               # doc ids / HBM
             pl.BlockSpec(memory_space=pl.ANY),               # scores / HBM
         ],
         out_specs=(
-            pl.BlockSpec((k, b), lambda i, d: (0, 0)),       # shard values
-            pl.BlockSpec((k, b), lambda i, d: (0, 0)),       # shard ids
-            pl.BlockSpec((1, 1), lambda i, d: (0, 0)),       # skip count
+            pl.BlockSpec((k, b), lambda i: (0, 0)),          # shard values
+            pl.BlockSpec((k, b), lambda i: (0, 0)),          # shard ids
+            pl.BlockSpec((1, 1), lambda i: (0, 0)),          # skip count
         ),
         scratch_shapes=(
             [pltpu.VMEM((block_size, b), weights.dtype),     # block acc
@@ -618,11 +632,6 @@ def bm25_resident_score_topk_pruned(desc: jax.Array, weights: jax.Array,
              pltpu.VMEM((k, b), weights.dtype),              # fold staging
              pltpu.VMEM((k, b), jnp.int32)]
         ),
-    )
-    return pl.pallas_call(
-        functools.partial(_resident_kernel_pruned, block_size=block_size,
-                          frag=frag, k=k, n_docs=n_docs),
-        grid_spec=grid_spec,
         out_shape=(
             jax.ShapeDtypeStruct((k, b), weights.dtype),
             jax.ShapeDtypeStruct((k, b), jnp.int32),
@@ -649,7 +658,7 @@ def bm25_resident_score_topk(desc: jax.Array, weights: jax.Array,
     ``desc`` is the ``[6, nf_pad]`` int32 table from
     ``sparse.block_csr.fragment_plan`` — or its device-built twin
     (``sparse.fragment_device.plan_fragments_device``), which never leaves
-    HBM — scalar-prefetched to SMEM so it can drive DMA descriptors;
+    HBM — read from SMEM in chunks so it can drive DMA descriptors;
     ``doc_ids_res``/``scores_res`` are the ``[1, nnz_pad]`` HBM-resident
     CSC arrays of a ``sparse.block_csr.DeviceIndex`` — the ONLY posting
     data the kernel touches, and it never crosses the host→device boundary
@@ -687,17 +696,22 @@ def bm25_resident_score_topk(desc: jax.Array, weights: jax.Array,
             pltpu.SemaphoreType.DMA,
             pltpu.SemaphoreType.DMA,
         ]
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,                    # desc table -> SMEM
+    # the double-buffered kernel also reads fragment i+1's start
+    descs = [desc, desc] if double_buffer else [desc]
+    kernel = _resident_kernel_db if double_buffer else _resident_kernel
+    return pl.pallas_call(
+        functools.partial(kernel, block_size=block_size,
+                          frag=frag, k=k, n_docs=n_docs),
         grid=(nf,),
-        in_specs=[
-            pl.BlockSpec((u, b), lambda i, d: (0, 0)),       # weights VMEM
+        in_specs=[_desc_spec(nf, ahead) for ahead in range(len(descs))]
+        + [
+            pl.BlockSpec((u, b), lambda i: (0, 0)),          # weights VMEM
             pl.BlockSpec(memory_space=pl.ANY),               # doc ids / HBM
             pl.BlockSpec(memory_space=pl.ANY),               # scores / HBM
         ],
         out_specs=(
-            pl.BlockSpec((k, b), lambda i, d: (0, 0)),       # shard values
-            pl.BlockSpec((k, b), lambda i, d: (0, 0)),       # shard ids
+            pl.BlockSpec((k, b), lambda i: (0, 0)),          # shard values
+            pl.BlockSpec((k, b), lambda i: (0, 0)),          # shard ids
         ),
         scratch_shapes=(
             [pltpu.VMEM((block_size, b), weights.dtype)]     # block acc
@@ -705,12 +719,6 @@ def bm25_resident_score_topk(desc: jax.Array, weights: jax.Array,
             + [pltpu.VMEM((k, b), weights.dtype),            # fold staging
                pltpu.VMEM((k, b), jnp.int32)]
         ),
-    )
-    kernel = _resident_kernel_db if double_buffer else _resident_kernel
-    return pl.pallas_call(
-        functools.partial(kernel, block_size=block_size,
-                          frag=frag, k=k, n_docs=n_docs),
-        grid_spec=grid_spec,
         out_shape=(
             jax.ShapeDtypeStruct((k, b), weights.dtype),
             jax.ShapeDtypeStruct((k, b), jnp.int32),
@@ -718,4 +726,4 @@ def bm25_resident_score_topk(desc: jax.Array, weights: jax.Array,
         interpret=interpret,
         name="bm25_resident_score_topk_db" if double_buffer
         else "bm25_resident_score_topk",
-    )(desc, weights, doc_ids_res, scores_res)
+    )(*descs, weights, doc_ids_res, scores_res)

@@ -192,7 +192,7 @@ class DeviceRetriever(_DeviceRetrieverBase):
     The gathered regime has two executions:
 
     * ``gather="resident"`` — fragment descriptors go to SMEM and the
-      scalar-prefetch kernel DMAs posting tiles straight out of the
+      resident kernel DMAs posting tiles straight out of the
       resident index (double-buffered: fragment f+1's copies overlap f's
       scatter; ``double_buffer=False`` keeps the sequential oracle).
       Where the fragment table is built is the ``plan`` axis:

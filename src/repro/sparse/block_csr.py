@@ -445,7 +445,7 @@ class RunDescriptors:
 
 @dataclass
 class FragmentPlan:
-    """SMEM descriptor table driving the resident scalar-prefetch kernel.
+    """SMEM descriptor table driving the resident gather kernels.
 
     The batch's posting runs, split at document-block boundaries into
     *segments* (one (token, block) pair each, grouped by block) and then

@@ -31,7 +31,7 @@ from repro.kernels.bm25_gather_score import (bm25_gather_score_topk,
 from repro.sparse.fragment_device import (block_bounds_device,
                                           build_fragment_table)
 
-B, U, FRAG, NF = 32, 256, 512, 4096
+B, U, FRAG, NF = 32, 256, 512, 1 << 17
 NNZ = N_DOCS * AVG_UNIQUE_TOKENS
 NNZ_PAD = -(-NNZ // FRAG) * FRAG + FRAG
 NB = N_DOCS // DOC_BLOCK
