@@ -18,6 +18,7 @@ import argparse
 import json
 import os
 import sys
+import time
 
 
 def main(argv=None) -> int:
@@ -26,8 +27,9 @@ def main(argv=None) -> int:
     ap.add_argument("--n-docs", type=int, default=None,
                     help="documents in the served corpus (default: the "
                          "deployment's 2,097,152)")
-    ap.add_argument("--queries", type=int, default=256)
+    ap.add_argument("--queries", type=int, default=128)
     args = ap.parse_args(argv)
+    t0 = time.perf_counter()
 
     import jax
     devices = jax.devices()
@@ -46,6 +48,8 @@ def main(argv=None) -> int:
     if args.n_docs is not None:
         cfg.n_docs = args.n_docs
     run_smoke(cfg)
+    print(f"setup whole run took {time.perf_counter() - t0:.1f} s",
+          flush=True)
     print(json.dumps({"ok": True, "device": {
         "platform": devices[0].platform,
         "kind": devices[0].device_kind,

@@ -49,7 +49,7 @@ class SmokeConfig:
     n_docs: int = N_DOCS
     n_vocab: int = N_VOCAB
     avg_len: int = AVG_DOC_LEN
-    n_queries: int = 256
+    n_queries: int = 128
     ks: tuple[int, ...] = (10, TOP_K)
     forced_batch: int = 8            # queries per forced-regime batch
     engine_batch: int = 32
