@@ -351,7 +351,8 @@ def _resident_kernel(desc_ref, w_ref, doc_hbm, sc_hbm, vals_ref, gid_ref,
                      block_size: int, frag: int, k: int, n_docs: int):
     """One fragment of the device-resident gather→score→top-k path.
 
-    The grid walks the batch's fragment table (SMEM chunks; see ``sparse.block_csr.FragmentPlan`` for the row layout). Each step
+    The grid walks the batch's fragment table (SMEM chunks; see
+    ``sparse.block_csr.FragmentPlan`` for the row layout). Each step
     DMAs its ≤``frag`` postings (doc ids + eager scores) out of the
     HBM-resident CSC arrays at a descriptor-driven dynamic offset, scales
     by the owning token's ``[B]`` query-weight row (named by the
