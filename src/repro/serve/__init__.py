@@ -76,6 +76,50 @@ breaker_threshold      ``DeviceRetriever``       3 (None disables)
 breaker_window_s       ``DeviceRetriever``       30.0
 breaker_cooldown_s     ``DeviceRetriever``       5.0
 ====================== ========================= =======================
+
+**Batch records — what the server just did.** :mod:`repro.obs` keeps
+one record per batch, always on, for the last :data:`repro.obs.RING`
+batches: ``repro.obs.batches()`` returns them oldest first, a flight
+recorder to read after a slow or failed batch. A record holds spans,
+``(name, batch, parent, t0_ns, t1_ns)`` on ``time.perf_counter_ns`` (the
+one clock every stage timer reads), and counters:
+
+============================ ============================================
+span                         host work it covers
+============================ ============================================
+``retriever.pack``           fault hook, sanitizer, pow2 pack
+                             (``timings["pack_s"]``)
+``retriever.retrieve``       everything below (``timings["execute_s"]``)
+``retriever.plan``           Σ df, the block-max survivor estimate where
+                             it runs, the regime choice
+``fragments.build``          one device fragment-table build: upload and
+                             dispatch (one per overflow retry)
+``fragments.overflow_wait``  host blocked reading the builder's fragment
+                             count
+``kernel.dispatch``          weight/shift upload and the scoring kernel's
+                             dispatch
+``board.wait``               host blocked reading the ``[B, k]`` board
+``board.finish``             finite check, ids, remap, result assembly
+``hop.<rung>``               a ladder rung other than the resident gather
+                             (``pruned``, ``host``, ``blocked``,
+                             ``oracle``)
+``frontend.queue``           one request, submit to its batch's flush
+                             (``timings["queue_s"]``)
+``frontend.pack_wait``       the batch, flush to pack start
+``frontend.exec_wait``       the batch, ready to execute start
+``engine.fanout``            shard fan-out up to quorum and deadline
+``engine.merge``             the shards' top-k merge
+============================ ============================================
+
+Counters: ``sum_df`` (the planner's Σ df), ``frag_builds`` (1 plus
+overflow retries), ``stream_positions`` (the builder's pow2 Σ df
+bucket), ``frags`` (real fragments) and ``frag_slots`` (the fragment
+bucket the kernel runs over). The ``frontend.*`` waits cross threads and
+are recorded only; every other span is also a
+``jax.profiler.TraceAnnotation``, so a trace taken with
+``jax.profiler.start_trace(dir)`` … ``stop_trace()`` (view it in
+TensorBoard's profile plugin or Perfetto) shows the spans on the host
+threads beside the device ops they dispatch and wait on.
 """
 
 from .errors import (AdmissionRejectedError, DeadlineExceededError,

@@ -59,12 +59,12 @@ k=...)`` surface, e.g. a :class:`RetrievalEngine` (single-stage path).
 from __future__ import annotations
 
 import threading
-import time
 from concurrent.futures import Future, ThreadPoolExecutor
 from dataclasses import dataclass, field
 
 import numpy as np
 
+from .. import obs
 from .errors import (AdmissionRejectedError, DeadlineExceededError,
                      QueueOverflowError, StageFailedError)
 from .health import health_envelope
@@ -84,7 +84,7 @@ class _Request:
 
     q: np.ndarray
     k: int
-    t_submit: float                      # monotonic admission time
+    t_submit_ns: int                     # admission time (obs.now_ns)
     future: Future = field(default_factory=Future)
     waited_s: float = 0.0                # set at flush time
 
@@ -315,7 +315,7 @@ class ServingFrontend:
         """
         q = np.asarray(query_tokens).ravel()
         kk = self.k if k is None else int(k)
-        req = _Request(q=q, k=kk, t_submit=time.monotonic())
+        req = _Request(q=q, k=kk, t_submit_ns=obs.now_ns())
         revive = False
         with self._cond:
             if self._stopping or not self._started:
@@ -349,7 +349,7 @@ class ServingFrontend:
             # depth and sheds (typed) — the real queue is untouched
             pending = int(_f.fire("queue.flood", pending))
         if self._admission is not None:
-            ra = self._admission.admit(time.monotonic(), pending)
+            ra = self._admission.admit(obs.now_ns() / 1e9, pending)
             if ra is not None:
                 self._shed += 1
                 self._rejected += 1
@@ -409,13 +409,14 @@ class ServingFrontend:
 
     # -- batch forming ----------------------------------------------------
 
-    def _pick_flush(self, now: float):
+    def _pick_flush(self, now_ns: int):
         """(key, reason) of the ripest bucket, or None if nothing's ripe."""
         for key, reqs in self._buckets.items():
             if len(reqs) >= self.max_batch:
                 return key, "size"
         for key, reqs in self._buckets.items():
-            if reqs and now - reqs[0].t_submit >= self.batch_deadline_s:
+            if reqs and (now_ns - reqs[0].t_submit_ns) / 1e9 \
+                    >= self.batch_deadline_s:
                 return key, "deadline"
         if self._stopping:
             for key, reqs in self._buckets.items():
@@ -423,13 +424,14 @@ class ServingFrontend:
                     return key, "drain"
         return None
 
-    def _next_wait(self, now: float) -> float | None:
+    def _next_wait(self, now_ns: int) -> float | None:
         """Seconds until the earliest deadline flush (None: sleep forever)."""
-        oldest = [reqs[0].t_submit for reqs in self._buckets.values()
+        oldest = [reqs[0].t_submit_ns for reqs in self._buckets.values()
                   if reqs]
         if not oldest:
             return None
-        return max(min(oldest) + self.batch_deadline_s - now, 0.0)
+        return max((min(oldest) - now_ns) / 1e9 + self.batch_deadline_s,
+                   0.0)
 
     def _former_loop(self) -> None:
         """Supervised former stage: crashes fail the in-flight batch
@@ -455,7 +457,7 @@ class ServingFrontend:
                 _f.fire("frontend.former", None)
         with self._cond:
             while True:
-                now = time.monotonic()
+                now = obs.now_ns()
                 pick = self._pick_flush(now)
                 if pick is not None:
                     break
@@ -517,12 +519,16 @@ class ServingFrontend:
             f"{type(exc).__name__}: {exc}", stage="former"))
         return out_of_budget
 
-    def _dispatch(self, reqs: list[_Request], kk: int, t_flush: float
+    def _dispatch(self, reqs: list[_Request], kk: int, t_flush: int
                   ) -> None:
-        """SLO-check a formed batch, then hand it to the pipeline."""
+        """SLO-check a formed batch, open its batch record (each request's
+        ``frontend.queue`` span, submit to flush), then hand it to the
+        pipeline."""
+        record = obs.new_batch()
         live = []
         for r in reqs:
-            r.waited_s = t_flush - r.t_submit
+            obs.interval(record, "frontend.queue", r.t_submit_ns, t_flush)
+            r.waited_s = (t_flush - r.t_submit_ns) / 1e9
             missed = (self.request_timeout_s is not None
                       and r.waited_s > self.request_timeout_s)
             if missed and self.on_miss == "raise":
@@ -544,44 +550,56 @@ class ServingFrontend:
         if not live:
             return
         if self._two_stage:
-            self._pack_pool.submit(self._pack_stage, live, kk)
+            self._pack_pool.submit(self._pack_stage, live, kk, record,
+                                   t_flush)
         else:
-            self._exec_pool.submit(self._exec_stage, live, kk, None)
+            self._exec_pool.submit(self._exec_stage, live, kk, None,
+                                   record, t_flush)
 
     # -- pipeline stages --------------------------------------------------
 
-    def _pack_stage(self, reqs: list[_Request], kk: int) -> None:
+    def _pack_stage(self, reqs: list[_Request], kk: int, record,
+                    t_flush: int) -> None:
         """Host pack (stage 1) — overlaps the previous batch's execute."""
+        obs.interval(record, "frontend.pack_wait", t_flush, obs.now_ns())
         try:
-            packed = self.retriever.pack_batch([r.q for r in reqs])
+            with obs.batch(record):
+                packed = self.retriever.pack_batch([r.q for r in reqs])
         except BaseException as e:
             self._fail(reqs, e)
             return
-        self._exec_pool.submit(self._exec_stage, reqs, kk, packed)
+        self._exec_pool.submit(self._exec_stage, reqs, kk, packed, record,
+                               obs.now_ns())
 
-    def _exec_stage(self, reqs: list[_Request], kk: int, packed) -> None:
-        """Device execute (stage 2) + per-request future resolution."""
+    def _exec_stage(self, reqs: list[_Request], kk: int, packed, record,
+                    t_ready: int) -> None:
+        """Device execute (stage 2) + per-request future resolution.
+        ``t_ready`` is when the batch was ready for this stage (packed,
+        or formed where there is no pack stage)."""
+        now = obs.now_ns()
+        obs.interval(record, "frontend.exec_wait", t_ready, now)
         if self._admission is not None and reqs:
             # CoDel input: this batch's oldest-request age at execution
             # start IS the standing queueing delay (the exec-pool queue
             # is the real backlog under overload, not the former's)
-            now = time.monotonic()
             with self._cond:
                 self._admission.observe(
-                    now - min(r.t_submit for r in reqs), now)
+                    (now - min(r.t_submit_ns for r in reqs)) / 1e9,
+                    now / 1e9)
         try:
-            if packed is not None:
-                res = self.retriever.retrieve_batch(None, kk,
-                                                    packed=packed)
-            else:
-                res = self.retriever.retrieve_batch([r.q for r in reqs],
-                                                    k=kk)
+            with obs.batch(record):
+                if packed is not None:
+                    res = self.retriever.retrieve_batch(None, kk,
+                                                        packed=packed)
+                else:
+                    res = self.retriever.retrieve_batch(
+                        [r.q for r in reqs], k=kk)
         except BaseException as e:
             self._fail(reqs, e)
             return
         if self.record_batches:
             self.recorded.append(([r.q for r in reqs], kk, res))
-        t_done = time.monotonic()
+        t_done = obs.now_ns()
         batch_degraded = bool(getattr(res, "degraded", False))
         for i, r in enumerate(reqs):
             missed = (self.request_timeout_s is not None
@@ -592,10 +610,10 @@ class ServingFrontend:
                 degradations=list(getattr(res, "degradations", [])),
                 degraded=batch_degraded or missed,
                 shards_answered=getattr(res, "shards_answered", None),
-                latency_s=t_done - r.t_submit,
+                latency_s=(t_done - r.t_submit_ns) / 1e9,
                 timings={**getattr(res, "timings", {}),
                          "queue_s": r.waited_s,
-                         "total_s": t_done - r.t_submit})
+                         "total_s": (t_done - r.t_submit_ns) / 1e9})
             with self._cond:
                 self._pending -= 1
                 self._served += 1

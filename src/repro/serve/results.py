@@ -16,8 +16,10 @@ them:
   (``[{"from", "to", "error", "detail"}, ...]``, empty on the healthy
   path; see ROADMAP "Fault tolerance");
 * ``timings`` — seconds per serving stage, keyed by stage name
-  (``"total_s"`` always present; the micro-batching frontend adds
-  ``"queue_s"``/``"pack_s"``/``"execute_s"``);
+  (``"total_s"`` always present). ``"pack_s"`` and ``"execute_s"`` are
+  the durations of the batch's ``retriever.pack`` and
+  ``retriever.retrieve`` spans (:mod:`repro.obs`); the micro-batching
+  frontend adds ``"queue_s"``, the request's ``frontend.queue`` span;
 * ``degraded`` / ``shards_answered`` / ``latency_s`` — the engine-level
   hedging fields the old engine dataclass carried (single-retriever
   results leave ``shards_answered`` None and set ``degraded`` iff the
@@ -91,7 +93,8 @@ class PackedBatch:
     uniq_tab: np.ndarray         # [u_max] padded unique-token table
     weights: np.ndarray          # [u_max, B_pad] per-query token weights
     shift: np.ndarray            # [B_pad] nonoccurrence shifts
-    pack_s: float = 0.0          # host seconds spent packing
+    pack_s: float = 0.0          # the ``retriever.pack`` span's seconds
+    record: object = None        # the batch's repro.obs.BatchRecord
 
 
 __all__ = ["RetrievalResult", "PackedBatch"]

@@ -63,6 +63,7 @@ from typing import Callable, Sequence
 
 import numpy as np
 
+from .. import obs
 from ..core.index import BM25Index, reshard_index
 from ..core.reference import ScipyBM25
 from ..core.retrieval import merge_topk
@@ -530,12 +531,15 @@ class DeviceRetriever(_DeviceRetrieverBase):
         calls pay one-off compiles that a serving-sized deadline would
         misread as stalls.
         """
+        record = obs.current()
+
         def body():
             _f = _faults_module()
             if _f is not None and _f.ACTIVE:
                 _f.fire("kernel.stall")
-            return self._exec_hop(hop, qs, b, uniq_batch, uniq_tab,
-                                  weights, shift, kk, plan, prune_ub)
+            with obs.batch(record):      # the watchdog's worker records too
+                return self._exec_hop(hop, qs, b, uniq_batch, uniq_tab,
+                                      weights, shift, kk, plan, prune_ub)
 
         if self._watchdog is not None and not strict:
             try:
@@ -562,11 +566,17 @@ class DeviceRetriever(_DeviceRetrieverBase):
         constructor's ``on_fault``); strict packs surface faults instead
         of entering the recoverable guard scope.
         """
+        with obs.batch() as record, obs.span("retriever.pack") as sp:
+            packed = self._pack(query_tokens, strict)
+        packed.record = record
+        packed.pack_s = sp.seconds
+        return packed
+
+    def _pack(self, query_tokens, strict) -> PackedBatch:
         import contextlib
 
         from ..core.retrieval import validate_query_batch
 
-        t0 = time.perf_counter()
         if strict is None:
             strict = self.on_fault == "raise"
         _f = _faults_module()
@@ -597,11 +607,9 @@ class DeviceRetriever(_DeviceRetrieverBase):
                         self.query_counters.get(key, 0) + v
         if self.n_docs == 0:                     # empty shard post-rescale
             return PackedBatch(qs, len(qs), np.zeros(0, np.int32), None,
-                               None, None,
-                               pack_s=time.perf_counter() - t0)
+                               None, None)
         b, uniq_batch, uniq_tab, weights, shift = self._pack_batch(qs)
-        return PackedBatch(qs, b, uniq_batch, uniq_tab, weights, shift,
-                           pack_s=time.perf_counter() - t0)
+        return PackedBatch(qs, b, uniq_batch, uniq_tab, weights, shift)
 
     def retrieve_batch(self, query_tokens: Sequence[np.ndarray] | None,
                        k: int, *, regime: str | None = None,
@@ -629,28 +637,35 @@ class DeviceRetriever(_DeviceRetrieverBase):
         may be None) — the sanitizer and fault hook already ran at pack
         time, so results are bit-identical to the one-call path.
         """
+        strict = regime is not None or self.on_fault == "raise"
+        if packed is None:
+            packed = self.pack_batch(query_tokens, strict=strict)
+        with obs.batch(packed.record), \
+                obs.span("retriever.retrieve") as sp:
+            res = self._retrieve_packed(packed, k, regime, strict)
+        res.timings = {"pack_s": packed.pack_s, "execute_s": sp.seconds,
+                       "total_s": packed.pack_s + sp.seconds}
+        res.latency_s = packed.pack_s + sp.seconds
+        return res
+
+    def _retrieve_packed(self, packed, k, regime, strict
+                         ) -> RetrievalResult:
+        """Plan, then walk the ladder from the entry rung (the execution
+        half of :meth:`retrieve_batch`; timings are the caller's)."""
         import contextlib
 
         from ..core.retrieval import plan_retrieval
 
-        strict = regime is not None or self.on_fault == "raise"
         _f = _faults_module()
         # recoverable-scope guard for the EXECUTION stages (see
         # pack_batch for the strictness rationale)
         guard = (_f.guard if _f is not None and not strict
                  else contextlib.nullcontext)
-        if packed is None:
-            packed = self.pack_batch(query_tokens, strict=strict)
-        t_start = time.perf_counter()            # exec clock excludes pack
         qs = packed.qs
         self.last_queries = qs
         if self.n_docs == 0 or k <= 0:           # empty shard post-rescale
             ids0, sc0 = _empty_batch(len(qs))
-            return RetrievalResult(
-                ids=ids0, scores=sc0,
-                timings={"pack_s": packed.pack_s, "execute_s": 0.0,
-                         "total_s": packed.pack_s},
-                latency_s=packed.pack_s)
+            return RetrievalResult(ids=ids0, scores=sc0)
         b, uniq_batch, uniq_tab, weights, shift = (
             packed.b, packed.uniq_batch, packed.uniq_tab, packed.weights,
             packed.shift)
@@ -660,19 +675,22 @@ class DeviceRetriever(_DeviceRetrieverBase):
         prune_ok = self._hop_available("pruned", kk)
         want = regime or self.regime
         survivor_frac, prune_ub = None, None
-        # the host estimate feeds the auto cost model and (under host
-        # planning) hands its bound matrix to the execution pass; a FORCED
-        # pruned regime under device planning consumes neither — skip the
-        # O(U·nb·B) host matmul on that hot path
-        if prune_ok and (want == "auto"
-                         or (want == "pruned" and self.plan_mode == "host")):
-            from ..sparse.block_csr import estimate_prune_survivors
-            survivor_frac, prune_ub = estimate_prune_survivors(
-                self.dindex.bmax, uniq_tab, weights, k=kk, b_true=b)
-        plan = plan_retrieval(self.dindex.sum_df(uniq_batch),
-                              self.dindex.nnz, regime=want,
-                              crossover=self.crossover, plan=self.plan_mode,
-                              survivor_frac=survivor_frac)
+        with obs.span("retriever.plan"):
+            # the host estimate feeds the auto cost model and (under host
+            # planning) hands its bound matrix to the execution pass; a
+            # FORCED pruned regime under device planning consumes neither
+            # — skip the O(U·nb·B) host matmul on that hot path
+            if prune_ok and (want == "auto" or (want == "pruned"
+                                                and self.plan_mode == "host")):
+                from ..sparse.block_csr import estimate_prune_survivors
+                survivor_frac, prune_ub = estimate_prune_survivors(
+                    self.dindex.bmax, uniq_tab, weights, k=kk, b_true=b)
+            plan = plan_retrieval(self.dindex.sum_df(uniq_batch),
+                                  self.dindex.nnz, regime=want,
+                                  crossover=self.crossover,
+                                  plan=self.plan_mode,
+                                  survivor_frac=survivor_frac)
+            obs.count("sum_df", plan.sum_df)
         self.last_plan = plan
         if plan.regime == "pruned" and not prune_ok:
             if self.gather_mode != "resident":
@@ -724,22 +742,15 @@ class DeviceRetriever(_DeviceRetrieverBase):
             # bounded budget before burning a ladder hop (strict calls
             # surface the first fault instead)
             delays = self._retry.delays() if not strict else []
-            board = None
-            while board is None:
+            while True:
                 try:
                     ids, vals = self._run_hop(
                         hop, qs, b, uniq_batch, uniq_tab, weights, shift,
                         kk, plan, prune_ub, strict=strict, guard_cm=guard)
-                    cand = np.asarray(vals)[:b].astype(np.float32,
-                                                       copy=False)
-                    # cheap integrity gate on the [B, k] board — NOT the
-                    # full score matrix (which never materializes on
-                    # these paths)
-                    if not np.isfinite(cand).all():
-                        raise ScoreIntegrityError(
-                            f"non-finite entries in the [{b}, {kk}] "
-                            f"score board returned by the {hop!r} hop")
-                    board = cand
+                    with obs.span("board.wait"):
+                        vals = np.asarray(vals)
+                    with obs.span("board.finish"):
+                        return self._finish(hop, ids, vals, b, kk, plan)
                 except RetrievalError as e:
                     name = type(e).__name__
                     with self._health_lock:
@@ -757,51 +768,58 @@ class DeviceRetriever(_DeviceRetrieverBase):
                                   "detail": str(e)})
                     last_err = e
                     break
-            if board is None:
-                continue
-            self._breaker_record(hop, ok=True)
-            if trail:
-                with self._health_lock:
-                    self.batches_degraded += 1
-                    for t in trail:
-                        key = f"{t['from']}->{t['to']}"
-                        self.degradation_counts[key] = \
-                            self.degradation_counts.get(key, 0) + 1
-            ids = np.asarray(ids)[:b].astype(np.int64)
-            perm = getattr(self.dindex, "perm", None)
-            if perm is not None:
-                # doc-id reordering: every hop scored in the permuted id
-                # space — ONE host-side gather on the [B, k] board maps
-                # winners back to client ids (zero extra device bytes)
-                from ..sparse.reorder import remap_board
-                ids = remap_board(ids, board, perm)
-            exec_s = time.perf_counter() - t_start
-            return RetrievalResult(
-                ids=ids + self.index.doc_offset, scores=board, plan=plan,
-                degradations=list(trail), degraded=bool(trail),
-                timings={"pack_s": packed.pack_s, "execute_s": exec_s,
-                         "total_s": packed.pack_s + exec_s},
-                latency_s=packed.pack_s + exec_s)
         raise RetrievalError(
             f"every ladder hop failed or is unavailable (entry "
             f"{entry!r}, degradations {trail!r})") from last_err
 
+    def _finish(self, hop, ids, vals, b, kk, plan) -> RetrievalResult:
+        """Check the board and assemble the batch's result: a cheap
+        integrity gate on the ``[B, k]`` board (NOT the full score
+        matrix, which never materializes on these paths), the ladder's
+        bookkeeping, and the id remap."""
+        board = vals[:b].astype(np.float32, copy=False)
+        if not np.isfinite(board).all():
+            raise ScoreIntegrityError(
+                f"non-finite entries in the [{b}, {kk}] score board "
+                f"returned by the {hop!r} hop")
+        self._breaker_record(hop, ok=True)
+        trail = plan.degradations
+        if trail:
+            with self._health_lock:
+                self.batches_degraded += 1
+                for t in trail:
+                    key = f"{t['from']}->{t['to']}"
+                    self.degradation_counts[key] = \
+                        self.degradation_counts.get(key, 0) + 1
+        ids = np.asarray(ids)[:b].astype(np.int64)
+        perm = getattr(self.dindex, "perm", None)
+        if perm is not None:
+            # doc-id reordering: every hop scored in the permuted id
+            # space — ONE host-side gather on the [B, k] board maps
+            # winners back to client ids (zero extra device bytes)
+            from ..sparse.reorder import remap_board
+            ids = remap_board(ids, board, perm)
+        return RetrievalResult(
+            ids=ids + self.index.doc_offset, scores=board, plan=plan,
+            degradations=list(trail), degraded=bool(trail))
+
     def _exec_hop(self, hop, qs, b, uniq_batch, uniq_tab, weights, shift,
                   kk, plan, prune_ub):
-        if hop == "pruned":
-            return self._retrieve_pruned(uniq_batch, uniq_tab, weights,
-                                         shift, kk, plan, b_true=b,
-                                         ub=prune_ub)
-        if hop == "resident":
+        if hop == "resident":          # the served path: spans of its own
             return self._exec_resident(uniq_batch, uniq_tab, weights,
                                        shift, kk, plan)
-        if hop == "host":
-            return self._exec_host(uniq_batch, uniq_tab, weights, shift,
-                                   kk)
-        if hop == "blocked":
-            return self._exec_blocked(uniq_tab, weights, shift, kk)
-        if hop == "oracle":
-            return self._exec_oracle(qs, kk)
+        with obs.span(f"hop.{hop}"):
+            if hop == "pruned":
+                return self._retrieve_pruned(uniq_batch, uniq_tab, weights,
+                                             shift, kk, plan, b_true=b,
+                                             ub=prune_ub)
+            if hop == "host":
+                return self._exec_host(uniq_batch, uniq_tab, weights, shift,
+                                       kk)
+            if hop == "blocked":
+                return self._exec_blocked(uniq_tab, weights, shift, kk)
+            if hop == "oracle":
+                return self._exec_oracle(qs, kk)
         raise AssertionError(f"unknown ladder hop {hop!r}")
 
     def _exec_blocked(self, uniq_tab, weights, shift, kk):
@@ -837,9 +855,11 @@ class DeviceRetriever(_DeviceRetrieverBase):
             # resident CSC arrays — no host CSC read, no descriptor
             # upload (the tier-1 zero-descriptor-bytes invariant)
             from ..sparse.fragment_device import plan_fragments_device
-            desc, dids, _nf = plan_fragments_device(
+            desc, dids, _, nf = plan_fragments_device(
                 self.dindex, uniq_tab, sum_df=plan.sum_df, k=kk,
                 block_size=rblock, state=self._nf_state)
+            if nf is not None:
+                plan.frags_planned = nf
         else:
             if not self._host_postings_intact():
                 raise ResidencyError('plan="host" fragment planning needs '
@@ -849,12 +869,14 @@ class DeviceRetriever(_DeviceRetrieverBase):
             dids = jnp.asarray(default_doc_ids(fp.vis_blocks, kk,
                                                self.n_docs, rblock))
             desc = put_descriptor_array(fp.desc)
-        return ops.bm25_retrieve_resident(
-            desc, jnp.asarray(weights),
-            self.dindex.csc_doc_ids, self.dindex.csc_scores,
-            dids, jnp.asarray(shift), block_size=rblock,
-            frag=self.dindex.frag, k=kk, n_docs=self.n_docs,
-            double_buffer=self.double_buffer)
+            plan.frags_planned = fp.n_frags
+        with obs.span("kernel.dispatch"):
+            return ops.bm25_retrieve_resident(
+                desc, jnp.asarray(weights),
+                self.dindex.csc_doc_ids, self.dindex.csc_scores,
+                dids, jnp.asarray(shift), block_size=rblock,
+                frag=self.dindex.frag, k=kk, n_docs=self.n_docs,
+                double_buffer=self.double_buffer)
 
     def _exec_host(self, uniq_batch, uniq_tab, weights, shift, kk):
         import jax.numpy as jnp
@@ -956,7 +978,7 @@ class DeviceRetriever(_DeviceRetrieverBase):
                                                   plan_fragments_device,
                                                   prune_fragment_mask,
                                                   seed_fragment_mask)
-            desc_full, dids, _ = plan_fragments_device(
+            desc_full, dids, _, _ = plan_fragments_device(
                 self.dindex, uniq_tab, sum_df=plan.sum_df, k=kk,
                 block_size=rblock, state=self._nf_state)
             nf_planned = int(np.asarray((desc_full[1] > 0).sum()))
@@ -1403,31 +1425,36 @@ class RetrievalEngine:
 
     # -- data plane ----------------------------------------------------------
     def _scatter_gather(self, submit, merge, k: int):
-        """Shared hedged scatter-gather: quorum + deadline + merge."""
-        t0 = time.time()
-        futures = {submit(rt): i for i, rt in enumerate(self.runtimes)}
-        need = max(1, int(np.ceil(self.quorum * len(self.runtimes))))
-        done: dict[int, tuple[np.ndarray, np.ndarray]] = {}
-        pending = set(futures)
-        deadline = t0 + self.deadline_s
-        while pending:
-            timeout = deadline - time.time()
-            if timeout <= 0 and len(done) >= need:
-                break                     # quorum met, deadline passed
-            finished, pending = wait(
-                pending, timeout=max(timeout, 0.005),
-                return_when=FIRST_COMPLETED)
-            for f in finished:
-                done[futures[f]] = f.result()
-            if not finished and len(done) >= need:
-                break
-        for f in pending:                 # backfill continues off-path
-            f.cancel()
-        ids, scores = merge(done.values(), k)
+        """Shared hedged scatter-gather: quorum + deadline + merge, as the
+        ``engine.fanout`` and ``engine.merge`` spans of one batch."""
+        with obs.batch():
+            t0 = obs.now_ns()
+            with obs.span("engine.fanout"):
+                futures = {submit(rt): i
+                           for i, rt in enumerate(self.runtimes)}
+                need = max(1, int(np.ceil(self.quorum
+                                          * len(self.runtimes))))
+                done: dict[int, tuple[np.ndarray, np.ndarray]] = {}
+                pending = set(futures)
+                while pending:
+                    timeout = self.deadline_s - (obs.now_ns() - t0) / 1e9
+                    if timeout <= 0 and len(done) >= need:
+                        break             # quorum met, deadline passed
+                    finished, pending = wait(
+                        pending, timeout=max(timeout, 0.005),
+                        return_when=FIRST_COMPLETED)
+                    for f in finished:
+                        done[futures[f]] = f.result()
+                    if not finished and len(done) >= need:
+                        break
+                for f in pending:         # backfill continues off-path
+                    f.cancel()
+            with obs.span("engine.merge"):
+                ids, scores = merge(done.values(), k)
+            latency = (obs.now_ns() - t0) / 1e9
         degraded = len(done) < len(self.runtimes)
         self._responses += 1
         self._degraded_responses += int(degraded)
-        latency = time.time() - t0
         return RetrievalResult(
             ids=ids, scores=scores, degraded=degraded,
             shards_answered=len(done), latency_s=latency,

@@ -45,6 +45,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .. import obs
 from .block_csr import _BOUND_ABS, _BOUND_SLACK, bucket_pow2
 
 _I32_BIG = np.iinfo(np.int32).max
@@ -189,8 +190,16 @@ def plan_fragments_device(dindex, uniq_tab, *, sum_df: int, k: int,
     the overflow flag up to the Σ df bucket, which always fits because
     every fragment carries at least one posting.
 
+    Each attempt is a ``fragments.build`` span (upload and dispatch) and,
+    below the Σ df bucket, a ``fragments.overflow_wait`` span: the one
+    read-back, of the fragment count ``nf``, which also decides overflow
+    (``nf > nf_pad``). The batch record (:mod:`repro.obs`) gets the
+    counters ``frag_builds``, ``stream_positions`` (``p_bucket``),
+    ``frag_slots`` (``nf_pad``) and, where it was read, ``frags``.
+
     Returns ``(desc [6, nf_pad] i32 device, def_ids [k] i32 device,
-    nf_bucket_used)``.
+    nf_bucket_used, nf)``; ``nf`` is None where the table was built at
+    the Σ df bucket, which always fits, so nothing was read back.
     """
     if dindex.csc_indptr is None or dindex.csc_doc_ids is None:
         from repro.serve.errors import ResidencyError
@@ -205,8 +214,9 @@ def plan_fragments_device(dindex, uniq_tab, *, sum_df: int, k: int,
         _f.fire("plan.fragments_device")
     block_size = block_size or dindex.block_size
     frag = dindex.frag
-    uniq_dev = jnp.asarray(np.asarray(uniq_tab, dtype=np.int32))
-    u = int(uniq_dev.shape[0])
+    uniq_np = np.asarray(uniq_tab, dtype=np.int32)
+    uniq_dev = None
+    u = int(uniq_np.shape[0])
     p_bucket = bucket_pow2(max(sum_df, 1), floor=8)
     cap = p_bucket                       # nf ≤ Σ df ≤ p_bucket, always fits
     if nf_bucket is not None:
@@ -216,17 +226,31 @@ def plan_fragments_device(dindex, uniq_tab, *, sum_df: int, k: int,
         nf_pad = min(bucket_pow2(est, floor=8), cap)
         if state is not None:
             nf_pad = min(max(nf_pad, state.get("nf", 8)), cap)
+    builds, nf = 0, None
     while True:
-        desc, def_ids, _nf, over = build_fragment_table(
-            uniq_dev, dindex.csc_indptr, dindex.csc_doc_ids,
-            block_size=block_size, frag=frag, nf_pad=nf_pad,
-            p_bucket=p_bucket, k=k, n_docs=dindex.n_docs)
-        if nf_pad >= cap or not bool(over):
+        with obs.span("fragments.build"):
+            if uniq_dev is None:
+                uniq_dev = jnp.asarray(uniq_np)
+            desc, def_ids, nf_dev, _ = build_fragment_table(
+                uniq_dev, dindex.csc_indptr, dindex.csc_doc_ids,
+                block_size=block_size, frag=frag, nf_pad=nf_pad,
+                p_bucket=p_bucket, k=k, n_docs=dindex.n_docs)
+        builds += 1
+        if nf_pad >= cap:
+            break
+        with obs.span("fragments.overflow_wait"):
+            nf = int(nf_dev)
+        if nf <= nf_pad:
             break
         nf_pad = min(nf_pad * 2, cap)    # overflow -> retry, never truncate
     if state is not None:
         state["nf"] = nf_pad
-    return desc, def_ids, nf_pad
+    obs.count("frag_builds", builds)
+    obs.count("stream_positions", p_bucket)
+    obs.count("frag_slots", nf_pad)
+    if nf is not None:
+        obs.count("frags", nf)
+    return desc, def_ids, nf_pad, nf
 
 
 # -- device half of the pruned regime ----------------------------------------

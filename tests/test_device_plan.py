@@ -73,7 +73,7 @@ def test_device_plan_matches_host_byte_for_byte(profile, rng):
     uniq = _profile_uniq(rng, profile, 48)
     fp = fragment_plan(idx, uniq, block_size=16, frag=8)
     sum_df = int(np.diff(idx.indptr)[uniq].sum())
-    desc, dids, nf_used = plan_fragments_device(
+    desc, dids, nf_used, _ = plan_fragments_device(
         di, _pad_uniq(uniq), sum_df=sum_df, k=5, block_size=16,
         nf_bucket=fp.nf_pad)
     assert nf_used == fp.nf_pad
@@ -95,7 +95,7 @@ def test_device_plan_empty_query_and_df0_tokens(rng):
     for uniq in cases:
         fp = fragment_plan(idx, uniq, block_size=16, frag=8)
         sum_df = int(df[uniq].sum())
-        desc, dids, _ = plan_fragments_device(
+        desc, dids, _, _ = plan_fragments_device(
             di, _pad_uniq(uniq), sum_df=sum_df, k=4, block_size=16,
             nf_bucket=fp.nf_pad)
         assert fp.n_frags == 0
@@ -124,10 +124,11 @@ def test_device_plan_overflow_flag_and_retry(rng):
         p_bucket=bucket_pow2(sum_df, floor=8), k=5,
         n_docs=int(idx.doc_lens.size))
     assert bool(over) and int(nf) == fp.n_frags
-    desc, _, nf_used = plan_fragments_device(
+    desc, _, nf_used, nf = plan_fragments_device(
         di, _pad_uniq(uniq), sum_df=sum_df, k=5, block_size=16,
         nf_bucket=8)                              # starts too small
     assert nf_used >= bucket_pow2(fp.n_frags, floor=8)
+    assert nf == fp.n_frags
     ref = fragment_plan(idx, uniq, block_size=16, frag=8,
                         nf_bucket=nf_used)
     np.testing.assert_array_equal(np.asarray(desc), ref.desc)
@@ -149,7 +150,7 @@ def test_property_device_plan_equals_host(seed, block_size, frag):
     fp = fragment_plan(idx, uniq, block_size=block_size, frag=frag)
     sum_df = int(np.diff(idx.indptr)[uniq].sum())
     k = int(rng.integers(1, 8))
-    desc, dids, _ = plan_fragments_device(
+    desc, dids, _, _ = plan_fragments_device(
         di, _pad_uniq(uniq), sum_df=sum_df, k=k, block_size=block_size,
         nf_bucket=fp.nf_pad)
     np.testing.assert_array_equal(np.asarray(desc), fp.desc)
@@ -169,7 +170,7 @@ def test_device_plan_wrapper_estimates_without_nf_bucket(rng):
     uniq = np.arange(32, dtype=np.int64)
     sum_df = int(np.diff(idx.indptr).sum())
     state = {}
-    desc, _, nf_used = plan_fragments_device(
+    desc, _, nf_used, _ = plan_fragments_device(
         di, _pad_uniq(uniq), sum_df=sum_df, k=5, block_size=16, state=state)
     fp = fragment_plan(idx, uniq, block_size=16, frag=8, nf_bucket=nf_used)
     np.testing.assert_array_equal(np.asarray(desc), fp.desc)
