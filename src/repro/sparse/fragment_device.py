@@ -16,10 +16,12 @@ byte-for-byte (tests assert equality of the emitted tables):
    for the batch's padded unique-token table (sentinel ``INT32_MAX`` rows
    contribute length 0);
 2. the flat posting stream is reconstructed positionally over a static
-   ``p_bucket`` budget (``searchsorted`` over the run-length cumsum — the
-   same trick as ``core.retrieval._device_gathered_topk``), and split into
-   *segments* wherever the owning run or the document block of
-   ``doc_ids[pos]`` changes;
+   ``p_bucket`` budget: each position's owning run and posting offset are
+   piecewise constant, changing only at the U run offsets, so two prefix
+   sums over markers scattered there give both (the run count, and the
+   telescoped per-run deltas of ``start - offset``) with no per-position
+   lookup; the stream is split into *segments* wherever the owning run or
+   the document block of ``doc_ids[pos]`` changes;
 3. segments are split into ≤``frag``-sized *fragments* (a cumulative-max
    recovers each position's segment start, so fragment boundaries fall at
    ``frag`` multiples inside every segment), compacted into a static
@@ -88,12 +90,20 @@ def build_fragment_table(uniq: jax.Array, indptr: jax.Array,
     starts = indptr[safe_u]
     lens = jnp.where(valid_u, indptr[safe_u + 1] - starts, 0)
 
-    # 2. flat stream positions + (owner run, doc block) per position
+    # 2. flat stream positions + (owner run, doc block) per position.
+    # Run u covers [off[u], off[u] + lens[u]); owner and start - offset
+    # change only at those offsets, so each is a prefix sum of markers
+    # there. An empty run shares its successor's offset and its delta
+    # telescopes away; offsets at p_bucket (Σ df fills the bucket) drop.
     cum = jnp.cumsum(lens)
     total = cum[u - 1]
-    owner = jnp.searchsorted(cum, iota_p, side="right").astype(jnp.int32)
-    owner = jnp.minimum(owner, u - 1)
-    pos = starts[owner] + (iota_p - (cum[owner] - lens[owner]))
+    off = cum - lens
+    d = starts - off
+    owner = jnp.cumsum(jnp.zeros((p_bucket,), jnp.int32).at[off[1:]].add(
+        1, mode="drop"))
+    step = d - jnp.concatenate([jnp.zeros((1,), jnp.int32), d[:-1]])
+    pos = iota_p + jnp.cumsum(jnp.zeros((p_bucket,), jnp.int32).at[off].add(
+        step, mode="drop"))
     ok = iota_p < total
     blk = jnp.where(ok, doc_ids_res[0, jnp.where(ok, pos, 0)] // block_size,
                     _I32_BIG)

@@ -62,25 +62,94 @@ def _profile_uniq(rng, profile: str, n_vocab: int) -> np.ndarray:
     return np.unique(rng.choice(pool, size=6)).astype(np.int64)
 
 
+# Edge profiles over a fixed 64-document corpus whose token df is known:
+# df 0 for tokens 0, 7, 9; 32 for 1 and 2; 16 for 3 and 4; 64 for 5; 8
+# for 6; 5 for 8. Each maps to (query tokens, floor of the padded table).
+EDGE_PROFILES = {
+    "pow2_fill": ([1, 2], 8),         # Σ df 64 == p_bucket, sentinels drop
+    "pow2_fill_empty_last": ([1, 2, 7], 4),
+    "empty_first": ([0, 3, 8], 8),
+    "empty_middle": ([3, 7, 8], 8),
+    "empty_last": ([1, 3, 8, 9], 1),  # U == 4: no sentinel after the run
+    "u1": ([8], 1),
+    "u1_fill": ([5], 1),
+    "all_sentinel": ([], 8),
+    "all_sentinel_u1": ([], 1),
+}
+
+
+def _edge_corpus() -> list:
+    docs = []
+    for j in range(64):
+        toks = [5]
+        toks += [1] if j < 32 else []
+        toks += [2] if j % 2 == 0 else []
+        toks += [3] if j < 16 else []
+        toks += [4] if j % 4 == 0 else []
+        toks += [6] if j < 8 else []
+        toks += [8] if j < 5 else []
+        docs.append(np.array(toks, np.int32))
+    return docs
+
+
 # -- tentpole: device fragment builder == host fragment_plan ------------------
 
-@pytest.mark.parametrize("profile", ["head", "tail", "dense"])
+@pytest.mark.parametrize("profile", ["head", "tail", "dense",
+                                     *EDGE_PROFILES])
 def test_device_plan_matches_host_byte_for_byte(profile, rng):
-    corpus = make_corpus(rng, n_docs=120, n_vocab=48, max_len=25)
-    idx = build_index(corpus, 48, params=BM25Params())
+    if profile in EDGE_PROFILES:
+        tokens, u_floor = EDGE_PROFILES[profile]
+        idx = build_index(_edge_corpus(), 10, params=BM25Params())
+        uniq = np.array(tokens, np.int64)
+    else:
+        u_floor = 8
+        corpus = make_corpus(rng, n_docs=120, n_vocab=48, max_len=25)
+        idx = build_index(corpus, 48, params=BM25Params())
+        uniq = _profile_uniq(rng, profile, 48)
     di = DeviceIndex.build(idx, block_size=16, tile=16, frag=8,
                            with_blocked=False)
-    uniq = _profile_uniq(rng, profile, 48)
     fp = fragment_plan(idx, uniq, block_size=16, frag=8)
     sum_df = int(np.diff(idx.indptr)[uniq].sum())
     desc, dids, nf_used, _ = plan_fragments_device(
-        di, _pad_uniq(uniq), sum_df=sum_df, k=5, block_size=16,
-        nf_bucket=fp.nf_pad)
+        di, _pad_uniq(uniq, floor=u_floor), sum_df=sum_df, k=5,
+        block_size=16, nf_bucket=fp.nf_pad)
     assert nf_used == fp.nf_pad
     np.testing.assert_array_equal(np.asarray(desc), fp.desc)
     np.testing.assert_array_equal(
         np.asarray(dids),
         default_doc_ids(fp.vis_blocks, 5, int(idx.doc_lens.size), 16))
+
+
+def _elements(tensor_type: str) -> int:
+    """``tensor<16384x1xi32>`` -> 16384 (element count of a type)."""
+    return int(np.prod([int(d) for d in tensor_type.split("x")[:-1]]))
+
+
+def test_builder_has_no_stream_sized_search_or_run_gathers():
+    """The owner/offset of each stream position comes from prefix sums:
+    no ``while`` (a ``searchsorted`` scan) carries a stream-sized array,
+    and the one stream-sized gather is the read of the postings."""
+    import re
+    import jax
+    import jax.numpy as jnp
+    p_bucket = 2 ** 14
+    i32 = jnp.int32
+    text = build_fragment_table.lower(
+        jax.ShapeDtypeStruct((64,), i32), jax.ShapeDtypeStruct((1001,), i32),
+        jax.ShapeDtypeStruct((1, 2 ** 15), i32), block_size=512, frag=128,
+        nf_pad=2 ** 10, p_bucket=p_bucket, k=10, n_docs=5000).as_text()
+    lines = text.splitlines()
+    whiles = [ln for ln in lines if "stablehlo.while" in ln]
+    assert whiles                        # the def_ids searchsorted stays
+    for ln in whiles:
+        assert all(_elements(t) != p_bucket
+                   for t in re.findall(r"tensor<([^>]*)>", ln)), ln
+    gathers = [ln for ln in lines if "stablehlo.gather" in ln]
+    stream = [ln for ln in gathers
+              if _elements(re.findall(r"-> tensor<([^>]*)>", ln)[-1])
+              == p_bucket]
+    assert len(stream) == 1, stream
+    assert "tensor<1x32768xi32>" in stream[0]    # doc_ids_res[0, pos]
 
 
 def test_device_plan_empty_query_and_df0_tokens(rng):
